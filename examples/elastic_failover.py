@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import init_cache, init_params, prefill
 from repro.runtime import elastic, serve
 from repro.runtime.checkpoint import CheckpointManager
@@ -67,7 +68,7 @@ def main():
     st = elastic.initial_state(cfg, 4, k=2)
     print(f"gen-{st.generation}: {len(st.stages)} stages, k={st.plan.k}, "
           f"w={st.plan.w}")
-    mesh4 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh4 = make_mesh((4, 2), ("data", "model"))
     toks_healthy = decode_on_ring(cfg, params, cache, tok0, mesh4,
                                   st.plan, steps=3)
     print("tokens (healthy)  :",
@@ -77,7 +78,7 @@ def main():
     st = elastic.fail_stages(st, cfg, [2, 3])
     print(f"gen-{st.generation}: {len(st.stages)} stages survive, "
           f"k={st.plan.k}, w={st.plan.w}")
-    mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh2 = make_mesh((2, 4), ("data", "model"))
     _, (cache_r, tok_r) = mgr.restore_latest(
         (jax.tree.map(jnp.zeros_like, cache), tok0))
     toks_failover = decode_on_ring(cfg, params, cache_r, tok_r, mesh2,

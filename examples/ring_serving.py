@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.data import ByteTokenizer, RequestGenerator
 from repro.models import init_cache, init_params, prefill
 from repro.runtime import serve
@@ -28,7 +29,7 @@ def main():
     cfg = dataclasses.replace(get_config("mixtral-8x7b").reduced(),
                               n_layers=8)   # 2 layers/stage -> k in {1,2}
     stages, tp = 4, 2
-    mesh = jax.make_mesh((stages, tp), ("data", "model"))
+    mesh = make_mesh((stages, tp), ("data", "model"))
     B, ctx, new_tokens = 8, 64, 12
 
     params = init_params(cfg, jax.random.PRNGKey(0))

@@ -6,16 +6,19 @@ which matters because decode is weight-bandwidth-bound) and dequantizes
 tile-by-tile in VMEM right before feeding the MXU.
 
 Layout: x (M, K) activations; packed (K/2, N) int8 (two int4 per byte along
-the contraction axis); scale (K/group, N). Block sizes keep every tile
-MXU-aligned (multiples of 128 on the matmul dims) and the working set
+the contraction axis); scale (K/group, N). x is split into its even and odd
+columns outside the kernel, which pair with the low and high nibbles. Block
+sizes keep every tile MXU-aligned (multiples of 128 on the matmul dims),
+the scale tile at 8 sublanes or more (``k_block``), and the working set
 within VMEM:
 
-  x tile (bm, bk) bf16            : bm*bk*2
-  packed tile (bk/2, bn) int8     : bk*bn/2
+  x tiles 2 x (bm, bk/2) bf16     : bm*bk*2
+  packed tile (bk/2, bn) int8     : bk*bn/2 (unpacked in int32/f32)
   scale tile (bk/g, bn)           : small
   out tile (bm, bn) f32 (+acc)    : bm*bn*4
 
-Default (256, 512, 256): 256*512*2 + 512*256/2 + 256*256*4 ~ 0.6 MiB.
+Group 64, bm=8 (decode), (bk, bn) = (512, 512): the unpacked f32 nibbles
+and their scales are 3 x 256*512*4 ~ 1.5 MiB.
 """
 from __future__ import annotations
 
@@ -27,39 +30,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names this TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+
+def k_block(group: int, block_k: int = 256) -> int:
+    """Contraction tile: at least 8 scale rows (bk / group), so the scale
+    tile fills the 8 sublanes of a TPU tile. A K no larger than this is
+    taken whole (a tile equal to the full dim is always legal)."""
+    return max(block_k, 8 * group)
 
 
-def _kernel(x_ref, packed_ref, scale_ref, out_ref, *, group: int,
-            n_k_blocks: int):
+def _kernel(xe_ref, xo_ref, packed_ref, scale_ref, out_ref, *, group: int):
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    x = x_ref[...]                                   # (bm, bk)
-    packed = packed_ref[...]                         # (bk/2, bn)
-    scale = scale_ref[...]                           # (bk/g, bn)
+    # unpack two int4 per byte in int32 (the chip has no int8 shifts):
+    # the low nibble is weight row 2i, the high nibble row 2i + 1, both
+    # sign-extended by the arithmetic shifts
+    p = packed_ref[...].astype(jnp.int32)            # (bk/2, bn)
+    lo = (p << 28) >> 28
+    hi = p >> 4
 
-    # unpack two int4 per byte (sign-extended)
-    lo = (packed & 0xF).astype(jnp.int8)
-    hi = ((packed >> 4) & 0xF).astype(jnp.int8)
-    lo = jnp.where(lo > 7, lo - 16, lo)
-    hi = jnp.where(hi > 7, hi - 16, hi)
-    kh, bn = packed.shape
-    w_q = jnp.stack([lo, hi], axis=1).reshape(kh * 2, bn)   # (bk, bn)
+    # rows 2i and 2i + 1 share scale row 2i // group = i // (group / 2)
+    scale = scale_ref[...].astype(jnp.float32)       # (bk/g, bn)
+    g_rows, bn = scale.shape
+    half = group // 2
+    scale_rows = jnp.broadcast_to(scale[:, None, :], (g_rows, half, bn)
+                                  ).reshape(g_rows * half, bn)
 
-    # broadcast per-group scales to per-row
-    g_rows = scale.shape[0]
-    scale_full = jnp.broadcast_to(scale[:, None, :], (g_rows, group, bn)
-                                  ).reshape(g_rows * group, bn)
-    w = w_q.astype(jnp.float32) * scale_full.astype(jnp.float32)
-
-    out_ref[...] += jnp.dot(x.astype(jnp.float32), w,
-                            preferred_element_type=jnp.float32)
+    # x's even and odd columns meet the low and high nibbles: no row
+    # interleave of the weight tile is needed
+    out_ref[...] += (
+        jnp.dot(xe_ref[...].astype(jnp.float32),
+                lo.astype(jnp.float32) * scale_rows,
+                preferred_element_type=jnp.float32)
+        + jnp.dot(xo_ref[...].astype(jnp.float32),
+                  hi.astype(jnp.float32) * scale_rows,
+                  preferred_element_type=jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("group", "block_m", "block_n",
@@ -74,21 +82,22 @@ def q4_matmul(x: jnp.ndarray, packed: jnp.ndarray, scale: jnp.ndarray, *,
     assert scale.shape == (K // group, N), (scale.shape, K, group, N)
     bm = min(block_m, M)
     bn = min(block_n, N)
-    bk = min(block_k, K)
-    assert K % bk == 0 and bk % group == 0
+    bk = min(k_block(group, block_k), K)
+    assert K % bk == 0 and bk % group == 0, (K, bk, group)
     assert M % bm == 0 and N % bn == 0
     grid = (M // bm, N // bn, K // bk)
+    x_spec = pl.BlockSpec((bm, bk // 2), lambda i, j, k: (i, k))
     return pl.pallas_call(
-        functools.partial(_kernel, group=group, n_k_blocks=K // bk),
+        functools.partial(_kernel, group=group),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+            x_spec, x_spec,
             pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
             pl.BlockSpec((bk // group, bn), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, packed, scale)
+    )(x[:, 0::2], x[:, 1::2], packed, scale)

@@ -1,3 +1,3 @@
 # NOTE: do not import .dryrun here — it sets XLA_FLAGS at import time and
 # must only be imported as the top-level entry point of its own process.
-from .mesh import make_debug_mesh, make_production_mesh  # noqa
+from .mesh import make_mesh  # noqa

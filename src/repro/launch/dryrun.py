@@ -2,8 +2,11 @@ import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512"
                            ).strip()
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax import/initialization: jax locks the device count
-#   on first init. This flag is dry-run-only; tests/benches see 1 device.
+#   on first init. The flag is dry-run-only; tests/benches see 1 device.
+#   The dry-run and the children it starts stay on the CPU, so they can
+#   never hold a chip that another process needs.
 
 """Multi-pod dry-run: prove the distribution config is coherent.
 
@@ -36,11 +39,15 @@ from ..runtime import serve
 from ..runtime.optim import AdamW
 from ..runtime.train import jitted_train_step
 from . import specs as SP
-from .mesh import make_production_mesh
+from .mesh import make_mesh
 
 _DTYPES = {"f32": 4, "f16": 2, "bf16": 2, "s32": 4, "u32": 4, "s8": 1,
            "u8": 1, "pred": 1, "s16": 2, "u16": 2, "f64": 8, "s64": 8,
            "u64": 8, "f8e4m3fn": 1, "f8e5m2": 1}
+
+#: the pod meshes the dry-run lowers for, on 512 virtual CPU devices
+_MESHES = {"single": ((16, 16), ("data", "model")),
+           "multi": ((2, 16, 16), ("pod", "data", "model"))}
 
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                 "collective-permute")
@@ -213,7 +220,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
              ring_k: int = 1, microbatch: Optional[int] = None,
              train_style: str = "fsdp", ring_quant: int = 0,
              keep_text: bool = False) -> Dict[str, Any]:
-    mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
+    mesh = make_mesh(*_MESHES[mesh_kind])
     t0 = time.time()
     lowered, meta = lower_cell(arch, shape_name, mesh, ring_k=ring_k,
                                microbatch=microbatch,

@@ -1,4 +1,4 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 A function, not a module-level constant — importing this module must never
 touch jax device state (the dry-run pins the device count before any jax
@@ -6,24 +6,31 @@ initialization).
 """
 from __future__ import annotations
 
+import math
+from typing import Optional, Sequence
+
 import jax
+from jax.sharding import AxisType
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 = 256 chips per pod; the multi-pod mesh adds a leading
-    2-pod data-parallel axis (512 chips)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: Sequence[int],
+              axes: Sequence[str] = ("data", "model"), *,
+              devices: Optional[Sequence] = None):
+    """Mesh of ``shape`` over the first ``prod(shape)`` devices present.
 
-
-def make_debug_mesh(n_stages: int = 4, tp: int = 2, *,
-                    multi_pod: bool = False):
-    """Small mesh for CPU tests (requires xla_force_host_platform_device_count
-    >= n_stages * tp [* 2])."""
-    if multi_pod:
-        return jax.make_mesh((2, n_stages, tp), ("pod", "data", "model"))
-    return jax.make_mesh((n_stages, tp), ("data", "model"))
+    Every axis is ``Auto``: the ring's ``shard_map`` steps and the GSPMD
+    paths' ``with_sharding_constraint`` both need sharding propagated by
+    the compiler, which ``jax.make_mesh``'s default ``Explicit`` axes
+    refuse. Raises when fewer devices exist than the mesh asks for.
+    """
+    devices = list(jax.devices() if devices is None else devices)
+    n = math.prod(shape)
+    if len(devices) < n:
+        raise ValueError(f"mesh {tuple(shape)} needs {n} devices, "
+                         f"{len(devices)} present")
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices[:n])
 
 
 # -- hardware constants (TPU v5e target) ------------------------------------

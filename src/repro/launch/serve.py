@@ -1,9 +1,9 @@
 """Serving driver: ``python -m repro.launch.serve --arch <id> --smoke``.
 
-Runs batched requests through prefill + piped-ring decode. On CPU the
-debug mesh is (data=4, model=2) over 8 forced host devices (set
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``); on a real pod the
-same code takes the production mesh.
+Runs batched requests through prefill + piped-ring decode on a
+(data=stages, model=tp) mesh over the devices present: the four chips of
+a TPU v5e host, or forced host devices on the CPU (set
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
 """
 from __future__ import annotations
 
@@ -19,7 +19,8 @@ from ..data import RequestGenerator
 from ..models import init_cache, init_params, prefill
 from ..runtime import serve as RS
 from ..runtime.telemetry import clock
-from .mesh import make_debug_mesh, make_production_mesh
+from .compile_cache import enable_compile_cache
+from .mesh import make_mesh
 
 
 def main(argv=None) -> int:
@@ -35,9 +36,11 @@ def main(argv=None) -> int:
                          "pass through the ring (weights streamed once "
                          "per pass) against T single-token steps")
     ap.add_argument("--ctx", type=int, default=64)
-    ap.add_argument("--mesh", choices=("debug", "prod"), default="debug")
-    ap.add_argument("--stages", type=int, default=4)
-    ap.add_argument("--tp", type=int, default=2)
+    ap.add_argument("--stages", type=int, default=0,
+                    help="ring stages (0 = as many as the devices present "
+                         "allow, at most the largest divisor of --batch)")
+    ap.add_argument("--tp", type=int, default=1,
+                    help="tensor-parallel chips inside each stage")
     ap.add_argument("--stream-window", type=int, default=0,
                     help="W>0: also run weight-streaming decode (mmap "
                          "layer store + async prefetcher keeping W layers "
@@ -135,18 +138,17 @@ def main(argv=None) -> int:
         from ..runtime.metrics import MetricsRegistry
         metrics = MetricsRegistry()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
-    if args.mesh == "prod":
-        mesh = make_production_mesh()
-        stages = 16
-        tp = 16
-    else:
-        mesh = make_debug_mesh(args.stages, args.tp)
-        stages, tp = args.stages, args.tp
+    B, tp = args.batch, args.tp
+    stages = args.stages or max(
+        m for m in range(1, len(jax.devices()) // tp + 1) if B % m == 0)
+    mesh = make_mesh((stages, tp))
+    print(f"mesh: {stages} stages x {tp} tp over "
+          f"{jax.devices()[0].platform} devices")
 
-    B = args.batch
     if not RS.ring_supported(cfg, B, stages):
         print(f"{cfg.name}: ring unsupported for B={B}, M={stages} "
               f"(family={cfg.family}) — GSPMD decode path")
@@ -228,8 +230,10 @@ def main(argv=None) -> int:
         dt = clock() - t0
         print(f"gspmd decode: {args.new_tokens} × {B} in {dt:.2f}s")
 
-    if args.stream_window > 0 and cfg.family in ("dense", "moe", "vlm",
-                                                 "ssm"):
+    if args.stream_window > 0:
+        if cfg.family not in ("dense", "moe", "vlm", "ssm"):
+            raise SystemExit(f"stream-window: unsupported family "
+                             f"{cfg.family}")
         _stream_smoke(cfg, params, prompts, args,
                       ring_ctx=(mesh, stages, tp) if ring else None,
                       tracer=tracer)
@@ -238,19 +242,16 @@ def main(argv=None) -> int:
         if args.kv_quant_kernel and cfg.kv_dtype != "int8":
             pcfg = dataclasses.replace(cfg, kv_dtype="int8")
         if cfg.family not in ("dense", "moe", "vlm"):
-            print(f"paged-kv: unsupported family {cfg.family} — skipped")
-        elif pcfg.kv_dtype == "int8" and pcfg.mla:
-            print("paged-kv: int8 MLA latent pages unsupported — skipped")
-        else:
-            _paged_smoke(pcfg, params, args, tracer=tracer,
-                         metrics=metrics)
+            raise SystemExit(f"paged-kv: unsupported family {cfg.family}")
+        if pcfg.kv_dtype == "int8" and pcfg.mla:
+            raise SystemExit("paged-kv: int8 MLA latent pages unsupported")
+        _paged_smoke(pcfg, params, args, tracer=tracer, metrics=metrics)
     if args.chaos != "none":
         if cfg.family not in ("dense", "moe", "vlm", "ssm"):
-            print(f"chaos: unsupported family {cfg.family} — skipped")
-        else:
-            _chaos_smoke(cfg, params, prompts, args,
-                         ring_ctx=(mesh, stages, tp) if ring else None,
-                         tracer=tracer)
+            raise SystemExit(f"chaos: unsupported family {cfg.family}")
+        _chaos_smoke(cfg, params, prompts, args,
+                     ring_ctx=(mesh, stages, tp) if ring else None,
+                     tracer=tracer)
     print("sample token ids:", np.asarray(nxt).ravel()[:8].tolist())
     if args.trace:
         from ..runtime.telemetry import format_summary
@@ -368,13 +369,8 @@ def _chaos_smoke(cfg, params, prompts, args, *, ring_ctx=None,
             from ..runtime.failover import ElasticRingServer
 
             if ring_ctx is None:
-                print("chaos failover: ring path unavailable — skipped")
-                return
+                raise SystemExit("chaos failover: needs the ring path")
             _, stages, tp = ring_ctx
-            if len(jax.devices()) < stages * tp:
-                print(f"chaos failover: needs {stages * tp} devices — "
-                      "skipped")
-                return
 
             class Counting:
                 def __init__(self, store):

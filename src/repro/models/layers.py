@@ -63,10 +63,13 @@ def q4_fused_eligible(w) -> bool:
     2-D q4 packing whose dims divide the kernel's MXU-aligned blocks."""
     if w.bits != 4 or w.packed.ndim != 2:
         return False
+    from ..kernels.q4_matmul import k_block
+
     K, N = w.packed.shape[0] * 2, w.packed.shape[1]
     if K % w.group or 256 % w.group:
         return False
-    return (K <= 256 or K % 256 == 0) and (N <= 512 or N % 512 == 0)
+    bk = k_block(w.group)
+    return (K <= bk or K % bk == 0) and (N <= 512 or N % 512 == 0)
 
 
 def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-5

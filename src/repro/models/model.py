@@ -423,6 +423,46 @@ def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray, *,
     return unembed(params, cfg, x)
 
 
+@partial(jax.jit, static_argnames=("cfg", "dtype"))
+def _upcast_layer(blocks: Params, cfg: ModelConfig, i, x, positions, dtype):
+    p = jax.tree.map(lambda a: lax.dynamic_index_in_dim(a, i, 0, False)
+                     .astype(dtype), blocks)
+    return _dense_block(cfg, p, x, positions, None, None, decode=False,
+                        tp_axis=None)[0]
+
+
+@partial(jax.jit, static_argnames=("dtype",))
+def _upcast_matmul(x, w, dtype):
+    return x @ w.astype(dtype)
+
+
+def forward_blocked(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
+                    *, dtype=jnp.float32, vocab_block: int = 16384
+                    ) -> jnp.ndarray:
+    """``forward`` on ``params`` upcast to ``dtype``, at the highest matmul
+    precision, one layer and one vocabulary block at a time.
+
+    The plain reference for a model whose whole upcast copy does not fit
+    the device: only one layer's weights and one block of the output
+    head exist in ``dtype`` at once. Upcasting is exact, so this equals
+    ``forward`` on the upcast parameter tree. Dense, MoE and VLM stacks
+    without frontend embeddings.
+    """
+    if cfg.family not in ("dense", "moe", "vlm"):
+        raise ValueError(f"blocked forward unsupported for {cfg.family}")
+    with jax.default_matmul_precision("highest"):
+        x = embed_tokens(params, cfg, tokens).astype(dtype)
+        B, S, _ = x.shape
+        positions = default_positions(cfg, B, S)
+        for i in range(cfg.n_layers):
+            x = _upcast_layer(params["blocks"], cfg, i, x, positions, dtype)
+        x = ll.rms_norm(x, params["final_norm"].astype(dtype), cfg.norm_eps)
+        w = params["unembed"] if "unembed" in params else params["embed"].T
+        return jnp.concatenate(
+            [_upcast_matmul(x, w[:, j:j + vocab_block], dtype)
+             for j in range(0, w.shape[1], vocab_block)], axis=-1)
+
+
 def prefill(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
             cache: Dict, *, embeds: Optional[jnp.ndarray] = None,
             positions: Optional[jnp.ndarray] = None,

@@ -145,15 +145,11 @@ class ElasticRingServer:
     def _build(self):
         """Mesh + fresh ring-permuted cache + streaming driver for the
         current elastic state."""
-        M = self.state.plan.n_stages
-        need = M * self.tp
-        devs = jax.devices()
-        if len(devs) < need:
-            raise RuntimeError(f"need {need} devices for M={M} x "
-                               f"tp={self.tp}, have {len(devs)}")
+        from ..launch.mesh import make_mesh
         from ..models import init_cache
-        mesh = jax.sharding.Mesh(
-            np.array(devs[:need]).reshape(M, self.tp), ("data", "model"))
+
+        M = self.state.plan.n_stages
+        mesh = make_mesh((M, self.tp))
         cache = init_cache(self.cfg, self.batch, self.ctx,
                            dtype=jnp.float32)
         cache["layers"] = RS.pad_and_permute(cache["layers"], self.cfg,

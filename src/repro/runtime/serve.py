@@ -40,21 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 exports shard_map at top level (with check_vma=)
-    from jax import shard_map as _shard_map
-
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_SHARD_MAP_CHECK_KW: check_vma})
 
 from ..configs.base import ModelConfig
 from ..models import layers as ll

@@ -10,8 +10,8 @@ import numpy as np
 import pytest
 
 from repro.configs import ASSIGNED_ARCHS, get_config
-from repro.models import (decode_step, forward, init_cache, init_params,
-                          prefill)
+from repro.models import (decode_step, forward, forward_blocked,
+                          init_cache, init_params, prefill)
 from repro.runtime.optim import AdamW
 from repro.runtime.train import lm_loss, make_train_step
 
@@ -78,6 +78,22 @@ def test_decode_matches_forward(arch):
     rel = max(errs) / float(jnp.max(jnp.abs(full)))
     tol = 2e-2 if cfg.kv_dtype == "int8" else 2e-4
     assert rel < tol, (arch, rel)
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-14b", "mixtral-8x7b"])
+def test_forward_blocked_is_upcast_forward(arch):
+    """The layer-at-a-time float32 reference equals ``forward`` on the
+    whole upcast parameter tree, vocabulary blocks included."""
+    cfg = get_config(arch).reduced()
+    params = init_params(cfg, KEY, dtype=jnp.bfloat16)
+    toks, _ = _inputs(cfg)
+    up = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+    with jax.default_matmul_precision("highest"):
+        ref = forward(up, cfg, toks)
+    got = forward_blocked(params, cfg, toks, vocab_block=48)
+    assert got.dtype == jnp.float32 and got.shape == ref.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_loss_decreases_dense():

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import decode_step, init_cache, init_params
 from repro.runtime import serve
 
@@ -52,7 +53,7 @@ def _run(arch, *, n_layers=8, k=1, B=8, Smax=32, steps=3, tol=2e-4,
     params = init_params(cfg, KEY)
     toks = jax.random.randint(KEY, (B, steps + 1), 0, cfg.vocab)
     refs = _reference(cfg, params, toks, B, Smax, steps)
-    mesh = jax.make_mesh(mesh_shape, axis_names)
+    mesh = make_mesh(mesh_shape, axis_names)
     n_stages = dict(zip(axis_names, mesh_shape))["data"]
     tp = dict(zip(axis_names, mesh_shape))["model"]
     outs = _ring(cfg, params, toks, B, Smax, steps, mesh, n_stages, tp, k)
@@ -131,7 +132,7 @@ def test_ring_verify_multi_token(arch):
         refs.append(lg[:, 0])
     ref = jnp.stack(refs, 1)                             # (B, T, V)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     plan = serve.RingPlan.make(cfg, 4, k=1)
     pr = serve.pad_vocab(dict(params), cfg, 2)
     pr["blocks"] = serve.pad_and_permute(params["blocks"], cfg, 4, 1)
@@ -169,7 +170,7 @@ def test_gspmd_decode_matches_reference():
     B, Smax, steps = 8, 32, 3
     toks = jax.random.randint(KEY, (B, steps + 1), 0, cfg.vocab)
     refs = _reference(cfg, params, toks, B, Smax, steps)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cache = init_cache(cfg, B, Smax, dtype=jnp.float32)
     step = serve.gspmd_decode_step(cfg, mesh, params, cache)
     for t in range(steps):

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import decode_step, init_cache, init_params
 from repro.runtime import serve
 
@@ -22,7 +23,7 @@ def test_ring_q4_matches_dequantized_reference(arch):
     key = jax.random.PRNGKey(0)
     params = init_params(cfg, key)
     B, Smax = 8, 32
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     toks = jax.random.randint(key, (B, 4), 0, cfg.vocab)
 
     # reference: plain decode with dequantized weights (same numerics

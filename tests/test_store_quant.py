@@ -17,6 +17,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.latency import quantized_layer_bytes
+from repro.launch.mesh import make_mesh
 from repro.models import (decode_step, decode_step_layerwise, init_cache,
                           init_params, prefill, prefill_layerwise)
 from repro.quant import QuantizedTensor, dequantize_tree, quantize_tree
@@ -222,7 +223,7 @@ def test_ring_stream_quantized_store(store_dir):
         lg, cache_r = decode_step(pd, cfg, cache_r, toks[:, t:t + 1])
         refs.append(lg)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     plan = serve.RingPlan.make(cfg, 4, k=2)
     head = {k: v for k, v in serve.pad_vocab(dict(params), cfg, 2).items()
             if k != "blocks"}

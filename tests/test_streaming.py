@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import (decode_step, decode_step_layerwise, forward,
                           forward_layerwise, init_cache, init_params,
                           prefill, prefill_layerwise)
@@ -273,7 +274,7 @@ def _ring_stream_parity(arch, *, n_layers=8, k=2, B=8, Smax=32, steps=3,
                                   toks[:, t * T:(t + 1) * T])
         refs.append(lg)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     plan = serve.RingPlan.make(cfg, 4, k=k)
     pr = serve.pad_vocab(dict(params), cfg, 2)
     head = {kk: v for kk, v in pr.items() if kk != "blocks"}
