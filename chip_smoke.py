@@ -18,7 +18,9 @@ reference (``models.forward_blocked``).
 Four chips. ``build_ring_serve_step`` on a (data=4, model=1) mesh, 8
 layers (2 per stage), a few teacher-forced decode steps; the logits are
 compared with ``models.decode_step`` on one chip and with the float32
-reference, and per-device memory shows the stages on all four chips.
+reference, and per-device memory shows the stages on all four chips. The
+same comparisons must fail for the ring with its stages out of order and
+for the stack computed in float8.
 
 Exits non-zero, with no result line, where JAX finds no TPU or the
 kernels would not be compiled. The last line of a passing run is one
@@ -152,18 +154,25 @@ def check_logits(name: str, got, ref) -> float:
     return worst
 
 
+def must_miss(name: str, got, ref) -> None:
+    """A control: logits of a deliberately wrong computation, which must
+    fail ``REL_TOL`` or the bound would not catch that fault."""
+    err = float(rel_err(got, ref).max())
+    log(f"control: {name} is off by {err:.3e}")
+    if not err > REL_TOL:
+        fail(f"tolerance {REL_TOL:.1e} would pass {name}")
+
+
 def check_tolerance(params, cfg, toks, ref) -> None:
     """The same stack computed plainly in bf16 and in float8: the first
     shows the rounding the served path cannot avoid, the second must fail
     ``REL_TOL``, or the bound would not tell bf16 from lower precision."""
-    for dtype, name in ((jnp.bfloat16, "bf16"),
-                        (jnp.float8_e4m3fn, "float8 e4m3")):
-        got = forward_blocked(params, cfg, toks, dtype=dtype)
-        err = float(rel_err(got, ref).max())
-        log(f"for scale: the same stack computed in {name} is off by "
-            f"{err:.3e}")
-        if dtype == jnp.float8_e4m3fn and err <= REL_TOL:
-            fail(f"tolerance {REL_TOL:.1e} would pass a float8 stack")
+    got = forward_blocked(params, cfg, toks, dtype=jnp.bfloat16)
+    log(f"for scale: the same stack computed in bf16 is off by "
+        f"{float(rel_err(got, ref).max()):.3e}")
+    must_miss("the same stack computed in float8 e4m3",
+              forward_blocked(params, cfg, toks, dtype=jnp.float8_e4m3fn),
+              ref)
 
 
 # --------------------------------------------------------------------------- #
@@ -242,6 +251,7 @@ def serve_phase(cfg, params, *, n_requests: int = 16, slots: int = 8,
     t0 = time.perf_counter()
     finished, steps = eng.run(kv.init_cache(), reqs)
     wall = time.perf_counter() - t0
+    live_kv = kv.stats().highwater_bytes
     kv.close()
     done = {f.uid: f.tokens for f in finished}
     if eng.rejected or len(done) != len(reqs):
@@ -281,7 +291,8 @@ def serve_phase(cfg, params, *, n_requests: int = 16, slots: int = 8,
                               ref[0, S:S + n_probe])
     check_tolerance(params, cfg, toks, ref)
     return {"requests": len(done), "err_prefill": err_prefill,
-            "err_decode": err_decode, "peak_bytes":
+            "err_decode": err_decode, "live_bytes":
+            tree_bytes(params) + live_kv, "peak_bytes":
             (stats or {}).get("peak_bytes_in_use", 0),
             "bytes_limit": (stats or {}).get("bytes_limit", 0)}
 
@@ -298,7 +309,10 @@ def run_one_chip(dev, seed: int) -> None:
     res = serve_phase(cfg, params, seed=seed)
     share = res["peak_bytes"] / res["bytes_limit"]
     log(f"HBM peak {res['peak_bytes'] / 1e9:.2f} GB = {share:.0%} of the "
-        f"chip")
+        f"chip; weights plus the KV pages the requests held at most "
+        f"{res['live_bytes'] / 1e9:.2f} GB = "
+        f"{res['live_bytes'] / res['bytes_limit']:.0%} (the rest is the "
+        f"unfilled pool, its per-step copies and temporaries)")
     if share < 0.6:
         fail(f"HBM peak {share:.0%} of the chip, below the 60% floor")
 
@@ -310,7 +324,9 @@ def run_one_chip(dev, seed: int) -> None:
 def ring_phase(cfg, params, mesh, *, batch: int = 8, ctx: int = 64,
                steps: int = 6, seed: int = 0) -> dict:
     """Teacher-forced decode through ``build_ring_serve_step`` against
-    ``models.decode_step`` on one device and the float32 reference."""
+    ``models.decode_step`` on one device and the float32 reference; then
+    the same step with every stage holding the next stage's layers, a
+    ring fault that the comparison must catch."""
     n_stages, tp = mesh.shape["data"], mesh.shape["model"]
     toks = jax.random.randint(jax.random.PRNGKey(seed + 1),
                               (batch, steps), 3, cfg.vocab, jnp.int32)
@@ -335,25 +351,38 @@ def ring_phase(cfg, params, mesh, *, batch: int = 8, ctx: int = 64,
         return jax.tree.map(lambda a, s: jax.device_put(
             prep(a), jax.sharding.NamedSharding(mesh, s)), tree, specs)
 
+    def ring_cache():
+        rc = init_cache(cfg, batch, ctx, dtype=jnp.bfloat16)
+        rc["layers"] = ring_order(rc["layers"])
+        return place(rc, RS.ring_cache_specs(cfg, mesh, rc))
+
     plan = RS.RingPlan.make(cfg, n_stages, k=1)
     pr = RS.pad_vocab(dict(params), cfg, tp)
     shapes = dict(pr, blocks=jax.eval_shape(ring_order, pr["blocks"]))
     pr = place(pr, RS.ring_param_specs(cfg, mesh, shapes),
                prep=lambda a: ring_order(a) if a.ndim and
                a.shape[0] == cfg.n_layers else a)
-    rc = init_cache(cfg, batch, ctx, dtype=jnp.bfloat16)
-    rc["layers"] = ring_order(rc["layers"])
-    rc = place(rc, RS.ring_cache_specs(cfg, mesh, rc))
-    step = RS.build_ring_serve_step(cfg, mesh, plan)(pr, rc)
-    ln = jnp.zeros((batch,), jnp.int32)
-    got = []
-    for t in range(steps):
-        logits, rc = step(toks[:, t:t + 1], ln, pr, rc)
-        ln = ln + 1
-        got.append(logits[:, 0, :cfg.vocab])
-    got = jnp.stack(got, 1)
-    return {"ring": got, "ref_bf16": ref_bf16, "ref32": ref32,
-            "ring_params": pr}
+    step = RS.build_ring_serve_step(cfg, mesh, plan)(pr, ring_cache())
+
+    def decode(ring_params):
+        rc, ln, out = ring_cache(), jnp.zeros((batch,), jnp.int32), []
+        for t in range(steps):
+            logits, rc = step(toks[:, t:t + 1], ln, ring_params, rc)
+            ln = ln + 1
+            out.append(logits[:, 0, :cfg.vocab])
+        return jnp.stack(out, 1)
+
+    got = decode(pr)
+    # the fault: the layer axis rolled by one stage, so that the stages
+    # run their layers in the wrong order (one stage's worth of extra
+    # weights per device, no recompile)
+    w = plan.L_pad // n_stages
+    roll = jax.jit(lambda b: jax.tree.map(lambda a: jnp.roll(a, w, 0), b),
+                   out_shardings=jax.tree.map(lambda a: a.sharding,
+                                              pr["blocks"]))
+    misordered = decode(dict(pr, blocks=roll(pr["blocks"])))
+    return {"ring": got, "misordered": misordered, "ref_bf16": ref_bf16,
+            "ref32": ref32, "toks": toks, "ring_params": pr}
 
 
 def run_four_chips(devices, seed: int) -> None:
@@ -361,9 +390,7 @@ def run_four_chips(devices, seed: int) -> None:
     log(f"config: {ARCH} at published widths; depth cut 48 -> "
         f"{cfg.n_layers} layers, 2 per stage on a 4-stage ring; bf16")
     params = init_weights(cfg, seed)
-    mesh = make_mesh((4, 1), ("data", "model"), devices=devices[:4])
-    res = ring_phase(cfg, params, mesh, seed=seed)
-    del params
+    res = ring_phase(cfg, params, make_mesh((4, 1)), seed=seed)
     for i, d in enumerate(devices[:4]):
         held = sum(s.data.nbytes for a in jax.tree.leaves(res["ring_params"])
                    for s in a.addressable_shards if s.device == d)
@@ -374,6 +401,11 @@ def run_four_chips(devices, seed: int) -> None:
                  res["ref_bf16"], res["ref32"])
     check_logits("ring vs single-chip decode_step", res["ring"],
                  res["ref_bf16"])
+    must_miss("the ring with its stages out of order, vs float32",
+              res["misordered"], res["ref32"])
+    must_miss("the ring with its stages out of order, vs decode_step",
+              res["misordered"], res["ref_bf16"])
+    check_tolerance(params, cfg, res["toks"], res["ref32"])
 
 
 def main(argv=None) -> int:

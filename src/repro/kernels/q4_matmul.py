@@ -31,11 +31,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def k_block(group: int, block_k: int = 256) -> int:
-    """Contraction tile: at least 8 scale rows (bk / group), so the scale
-    tile fills the 8 sublanes of a TPU tile. A K no larger than this is
-    taken whole (a tile equal to the full dim is always legal)."""
-    return max(block_k, 8 * group)
+def k_block(group: int) -> int:
+    """Contraction tile: 256, or 8 scale rows (8 * group) where that is
+    more, so the scale tile fills the 8 sublanes of a TPU tile. A K no
+    larger than this is taken whole (a tile equal to the full dim is
+    always legal)."""
+    return max(256, 8 * group)
 
 
 def _kernel(xe_ref, xo_ref, packed_ref, scale_ref, out_ref, *, group: int):
@@ -71,18 +72,20 @@ def _kernel(xe_ref, xo_ref, packed_ref, scale_ref, out_ref, *, group: int):
 
 
 @functools.partial(jax.jit, static_argnames=("group", "block_m", "block_n",
-                                             "block_k", "interpret"))
+                                             "interpret"))
 def q4_matmul(x: jnp.ndarray, packed: jnp.ndarray, scale: jnp.ndarray, *,
               group: int = 64, block_m: int = 256, block_n: int = 512,
-              block_k: int = 256, interpret: bool = False) -> jnp.ndarray:
-    """x: (M, K); packed: (K/2, N) int8; scale: (K/group, N). -> (M, N) f32."""
+              interpret: bool = False) -> jnp.ndarray:
+    """x: (M, K); packed: (K/2, N) int8; scale: (K/group, N). -> (M, N) f32.
+
+    K is tiled by ``k_block(group)``; M and N by ``block_m``/``block_n``."""
     M, K = x.shape
     N = packed.shape[1]
     assert packed.shape[0] * 2 == K
     assert scale.shape == (K // group, N), (scale.shape, K, group, N)
     bm = min(block_m, M)
     bn = min(block_n, N)
-    bk = min(k_block(group, block_k), K)
+    bk = min(k_block(group), K)
     assert K % bk == 0 and bk % group == 0, (K, bk, group)
     assert M % bm == 0 and N % bn == 0
     grid = (M // bm, N // bn, K // bk)

@@ -7,15 +7,14 @@ initialization).
 from __future__ import annotations
 
 import math
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
 from jax.sharding import AxisType
 
 
 def make_mesh(shape: Sequence[int],
-              axes: Sequence[str] = ("data", "model"), *,
-              devices: Optional[Sequence] = None):
+              axes: Sequence[str] = ("data", "model")):
     """Mesh of ``shape`` over the first ``prod(shape)`` devices present.
 
     Every axis is ``Auto``: the ring's ``shard_map`` steps and the GSPMD
@@ -23,7 +22,7 @@ def make_mesh(shape: Sequence[int],
     the compiler, which ``jax.make_mesh``'s default ``Explicit`` axes
     refuse. Raises when fewer devices exist than the mesh asks for.
     """
-    devices = list(jax.devices() if devices is None else devices)
+    devices = jax.devices()
     n = math.prod(shape)
     if len(devices) < n:
         raise ValueError(f"mesh {tuple(shape)} needs {n} devices, "

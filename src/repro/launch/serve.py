@@ -37,8 +37,9 @@ def main(argv=None) -> int:
                          "per pass) against T single-token steps")
     ap.add_argument("--ctx", type=int, default=64)
     ap.add_argument("--stages", type=int, default=0,
-                    help="ring stages (0 = as many as the devices present "
-                         "allow, at most the largest divisor of --batch)")
+                    help="ring stages (0 = the largest divisor of --batch "
+                         "that neither the devices present nor the layer "
+                         "count exceed)")
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel chips inside each stage")
     ap.add_argument("--stream-window", type=int, default=0,
@@ -143,8 +144,8 @@ def main(argv=None) -> int:
     if args.smoke:
         cfg = cfg.reduced()
     B, tp = args.batch, args.tp
-    stages = args.stages or max(
-        m for m in range(1, len(jax.devices()) // tp + 1) if B % m == 0)
+    most = min(len(jax.devices()) // tp, cfg.n_layers)
+    stages = args.stages or max(m for m in range(1, most + 1) if B % m == 0)
     mesh = make_mesh((stages, tp))
     print(f"mesh: {stages} stages x {tp} tp over "
           f"{jax.devices()[0].platform} devices")

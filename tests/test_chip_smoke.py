@@ -49,6 +49,7 @@ def test_serve_phase_tiny(no_kernel_check):
     assert res["requests"] == 5
     assert res["err_prefill"] <= cs.REL_TOL
     assert res["err_decode"] <= cs.REL_TOL
+    assert cs.tree_bytes(params) < res["live_bytes"]
 
 
 @pytest.mark.skipif(jax.device_count() < 4, reason="needs 4 devices")
@@ -59,6 +60,9 @@ def test_ring_phase_tiny():
     assert cs.check_logits("ring", res["ring"], res["ref32"]) <= cs.REL_TOL
     assert cs.check_logits("ring vs decode_step", res["ring"],
                            res["ref_bf16"]) <= cs.REL_TOL
+    cs.must_miss("misordered ring", res["misordered"], res["ref32"])
+    cs.must_miss("misordered ring vs decode_step", res["misordered"],
+                 res["ref_bf16"])
     held = {s.device for a in jax.tree.leaves(res["ring_params"]["blocks"])
             for s in a.addressable_shards}
     assert len(held) == 4

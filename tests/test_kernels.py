@@ -17,20 +17,20 @@ from repro.quant import quantize_q4
 KEY = jax.random.PRNGKey(0)
 
 
-@pytest.mark.parametrize("M,K,N,bm,bn,bk", [
-    (128, 256, 256, 128, 128, 128),
-    (256, 512, 512, 128, 256, 256),
-    (64, 128, 384, 64, 128, 64),
-    (256, 1024, 128, 256, 128, 512),
+@pytest.mark.parametrize("M,K,N,bm,bn,group", [
+    (128, 512, 256, 128, 128, 32),     # 2 K tiles of k_block(32) = 256
+    (256, 1024, 512, 128, 256, 64),    # 2 K tiles of 512
+    (64, 2048, 384, 64, 128, 64),      # 4 K tiles
+    (256, 128, 128, 256, 128, 64),     # K below k_block: one whole tile
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_q4_matmul_sweep(M, K, N, bm, bn, bk, dtype):
+def test_q4_matmul_sweep(M, K, N, bm, bn, group, dtype):
     x = jax.random.normal(KEY, (M, K), dtype)
     w = jax.random.normal(jax.random.PRNGKey(1), (K, N))
-    qt = quantize_q4(w)
-    out = q4_matmul(x, qt.packed, qt.scale, block_m=bm, block_n=bn,
-                    block_k=bk, interpret=True)
-    want = ref.q4_matmul_ref(x, qt.packed, qt.scale)
+    qt = quantize_q4(w, group)
+    out = q4_matmul(x, qt.packed, qt.scale, group=group, block_m=bm,
+                    block_n=bn, interpret=True)
+    want = ref.q4_matmul_ref(x, qt.packed, qt.scale, group=group)
     tol = 1e-3 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=tol, atol=tol * np.abs(want).max())
