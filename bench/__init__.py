@@ -1,0 +1,1 @@
+"""On-chip benchmark of the serving system: see PERF.md and BENCHMARK.json."""
