@@ -631,7 +631,7 @@ def _stream_smoke(cfg, params, prompts, args, *, ring_ctx=None,
             t0 = clock()
             for t in range(args.new_tokens):
                 with tracer.token_step(t, track="decode",
-                                       name=f"stream_token[{t}]"):
+                                       name="stream_token"):
                     with tracer.phase("compute"):
                         lg, c_s = decode_step_layerwise(src, cfg, c_s,
                                                         tok)

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .telemetry import NULL_TRACER, clock
+from .telemetry import NULL_TRACER, clock, compile_log
 
 
 @dataclasses.dataclass
@@ -131,6 +131,7 @@ class ContinuousBatcher:
                 "prefill_chunk requires a paged cache (kv) and a "
                 "chunk_step callable")
         self.tracer = tracer or NULL_TRACER
+        compile_log()                    # set-up is logged from here on
         self.metrics = metrics
         self._tracker = None
         if metrics is not None:
@@ -145,10 +146,6 @@ class ContinuousBatcher:
         self._deferred_n = 0             # admits deferred on pool pressure
         self._spec_proposed = 0
         self._spec_accepted = 0
-
-    def telemetry(self):
-        """The attached tracer (NULL_TRACER when tracing is off)."""
-        return self.tracer
 
     def streaming_stats(self):
         """Prefetch statistics of the attached streaming source (or None)."""
@@ -219,83 +216,97 @@ class ContinuousBatcher:
         contains it) and the first decode step resumes from the parked
         resume token, so the concatenated token stream is exactly what
         one uninterrupted request would have produced.
+
+        Traced as span ``batcher.admit`` (args ``uid``,
+        ``prompt_tokens``, ``prefix_hit_tokens``).
         """
-        if session is not None and self.spec is not None:
-            raise ValueError(
-                "session parking and speculative decoding cannot be "
-                "combined: the draft cache is not parked, so a restored "
-                "slot would verify against a cold draft")
-        free = self.free_slots()
-        if not free:
-            raise RuntimeError("no free slots")
-        slot = free[0]
-        tr = self._tracker
-        if tr is not None:
-            tr.submit(uid, prompt_len=len(prompt))   # no-op if already seen
-        t_admit = clock() if tr is not None else 0.0
-        if self.kv is not None and session is not None \
-                and self.kv.is_parked(session):
-            cache, meta, length = self.kv.restore_session(
-                cache, slot, session, max_new=max_new)
-            resume = int(meta["resume_token"])
-            tokens = tokens.at[slot, 0].set(resume)
-            # the resume token's KV is written by the first decode step,
-            # exactly as the last generated token's would have been — so
-            # remaining counts the full max_new and generated starts
-            # empty (the token was already emitted last turn).
-            self.slots[slot] = SlotState(uid=uid, remaining=max_new,
-                                         generated=[], session=session)
+        with self.tracer.span("batcher.admit", cat="sched", track="decode",
+                              uid=uid, prompt_tokens=len(prompt)) as sp:
+            if session is not None and self.spec is not None:
+                raise ValueError(
+                    "session parking and speculative decoding cannot be "
+                    "combined: the draft cache is not parked, so a restored "
+                    "slot would verify against a cold draft")
+            free = self.free_slots()
+            if not free:
+                raise RuntimeError("no free slots")
+            slot = free[0]
+            tr = self._tracker
             if tr is not None:
-                tr.admitted(uid, restored=True)
-                tr.prefill_done(uid, clock() - t_admit)
-            return cache, tokens
-        if self.kv is not None and self.prefill_chunk is not None:
-            self.kv.plan_admit(
-                cache, slot, [int(t) for t in np.asarray(prompt)],
-                max_new + (self.spec.gamma if self.spec else 0),
-                register=False)
-            try:
-                cache, tokens, first_tok = self._chunked_prefill(
-                    cache, tokens, slot, np.asarray(prompt), uid)
-            except BaseException:
-                # a failed chunk must not leak the planned pages
-                self.kv.abort_admit(slot)
-                raise
-        elif self.kv is not None:
-            margin = self.spec.gamma if self.spec is not None else 0
-            self.kv.plan_admit(cache, slot,
-                               [int(t) for t in np.asarray(prompt)],
-                               max_new + margin)
-            try:
+                tr.submit(uid, prompt_len=len(prompt))  # no-op if seen
+            t_admit = clock() if tr is not None else 0.0
+            if self.kv is not None and session is not None \
+                    and self.kv.is_parked(session):
+                cache, meta, length = self.kv.restore_session(
+                    cache, slot, session, max_new=max_new)
+                resume = int(meta["resume_token"])
+                tokens = tokens.at[slot, 0].set(resume)
+                # the resume token's KV is written by the first decode step,
+                # exactly as the last generated token's would have been — so
+                # remaining counts the full max_new and generated starts
+                # empty (the token was already emitted last turn).
+                self.slots[slot] = SlotState(uid=uid, remaining=max_new,
+                                             generated=[], session=session)
+                if tr is not None:
+                    tr.admitted(uid, t=t_admit, restored=True)
+                    tr.prefill_done(uid, clock() - t_admit)
+                return cache, tokens
+            if self.kv is not None and self.prefill_chunk is not None:
+                plan = self.kv.plan_admit(
+                    cache, slot, [int(t) for t in np.asarray(prompt)],
+                    max_new + (self.spec.gamma if self.spec else 0),
+                    register=False)
+                self._note_prefix_hits(sp, plan)
+                try:
+                    cache, tokens, first_tok = self._chunked_prefill(
+                        cache, tokens, slot, np.asarray(prompt), uid)
+                except BaseException:
+                    # a failed chunk must not leak the planned pages
+                    self.kv.abort_admit(slot)
+                    raise
+            elif self.kv is not None:
+                margin = self.spec.gamma if self.spec is not None else 0
+                plan = self.kv.plan_admit(cache, slot,
+                                          [int(t) for t in np.asarray(prompt)],
+                                          max_new + margin)
+                self._note_prefix_hits(sp, plan)
+                try:
+                    first_tok, slot_cache = self.prefill_one(
+                        jnp.asarray(prompt)[None, :])
+                    cache = self.kv.install(cache, slot, slot_cache["layers"],
+                                            len(prompt))
+                except BaseException:
+                    # a failed prefill must not leak the planned pages
+                    self.kv.abort_admit(slot)
+                    raise
+            else:
+                if self.ctx is not None and len(prompt) + max_new > self.ctx:
+                    raise ValueError(
+                        f"request {uid}: prompt ({len(prompt)}) + max_new "
+                        f"({max_new}) exceeds the cache context "
+                        f"({self.ctx}); the preallocated cache would "
+                        f"silently clip — raise ctx or trim the request")
                 first_tok, slot_cache = self.prefill_one(
                     jnp.asarray(prompt)[None, :])
-                cache = self.kv.install(cache, slot, slot_cache["layers"],
+                cache = self.write_slot(cache, slot_cache, slot,
                                         len(prompt))
-            except BaseException:
-                # a failed prefill must not leak the planned pages
-                self.kv.abort_admit(slot)
-                raise
-        else:
-            if self.ctx is not None and len(prompt) + max_new > self.ctx:
-                raise ValueError(
-                    f"request {uid}: prompt ({len(prompt)}) + max_new "
-                    f"({max_new}) exceeds the cache context ({self.ctx}); "
-                    f"the preallocated cache would silently clip — raise "
-                    f"ctx or trim the request")
-            first_tok, slot_cache = self.prefill_one(
-                jnp.asarray(prompt)[None, :])
-            cache = self.write_slot(cache, slot_cache, slot, len(prompt))
-        if self.spec is not None:
-            self.spec.admit(jnp.asarray(prompt)[None, :], slot, len(prompt))
-        tokens = tokens.at[slot, 0].set(first_tok)
-        self.slots[slot] = SlotState(uid=uid, remaining=max_new - 1,
-                                     generated=[int(first_tok)],
-                                     session=session)
-        if tr is not None:
-            tr.admitted(uid)
-            tr.prefill_done(uid, clock() - t_admit)
-            tr.token(uid)                # prefill emits the first token
-        return cache, tokens
+            if self.spec is not None:
+                self.spec.admit(jnp.asarray(prompt)[None, :], slot,
+                                len(prompt))
+            tokens = tokens.at[slot, 0].set(first_tok)
+            self.slots[slot] = SlotState(uid=uid, remaining=max_new - 1,
+                                         generated=[int(first_tok)],
+                                         session=session)
+            if tr is not None:
+                tr.admitted(uid, t=t_admit)
+                tr.prefill_done(uid, clock() - t_admit)
+                tr.token(uid)                # prefill emits the first token
+            return cache, tokens
+
+    def _note_prefix_hits(self, sp, plan) -> None:
+        if sp is not None:
+            sp["prefix_hit_tokens"] = (plan["shared"] + plan["fetched"]) \
+                * self.kv.page_tokens
 
     def _chunked_prefill(self, cache, tokens, slot: int,
                          prompt: np.ndarray, uid: int):
@@ -323,8 +334,9 @@ class ContinuousBatcher:
             view = {"pages": cache["pages"], "block_table": table1,
                     "len": jnp.full((1,), o, jnp.int32)}
             t0 = clock()
-            with self.tracer.span(f"prefill-chunk[{uid}:{n_chunks}]",
-                                  cat="compute", track="decode", uid=uid):
+            with self.tracer.span("batcher.chunk", cat="compute",
+                                  track="decode", uid=uid, chunk=n_chunks,
+                                  tokens=c):
                 logits, view = self.chunk_step(
                     view, jnp.asarray(prompt[o:o + c])[None, :], write)
                 logits.block_until_ready()
@@ -368,10 +380,6 @@ class ContinuousBatcher:
             self.kv.release_slot(i)
         return cache
 
-    def kv_stats(self):
-        """Allocator statistics of the attached paged cache (or None)."""
-        return self.kv.stats() if self.kv is not None else None
-
     def step(self, cache, tokens: jnp.ndarray):
         """One decode step for every occupied slot.
 
@@ -379,10 +387,14 @@ class ContinuousBatcher:
         argmax (host-synced) charge to ``compute``, stalls inside the
         decode callable (prefetcher waits, KV fetches) attribute to
         their own components, and the remainder books as scheduler
-        idle. Token-step records partition measured TPOT.
+        idle. Token-step records partition measured TPOT. The step is
+        span ``batcher.step`` (arg ``index``); inside it
+        ``kv.begin_step`` and ``batcher.readback``, the host waiting on
+        the device and copying the tokens back.
         """
         t0 = clock() if self._tracker is not None else 0.0
-        with self.tracer.token_step(self._step_idx, track="decode"):
+        with self.tracer.token_step(self._step_idx, track="decode",
+                                    name="batcher.step"):
             self._step_idx += 1
             if self.spec is not None:
                 out = self._spec_step(cache, tokens)
@@ -398,7 +410,9 @@ class ContinuousBatcher:
         with self.tracer.phase("compute", track="decode"):
             logits, cache = self.decode(cache, tokens)
             nxt = jnp.argmax(logits[:, 0], axis=-1)      # greedy
-            nxt_host = np.asarray(nxt)                   # force the sync
+            with self.tracer.span("batcher.readback", cat="sync",
+                                  track="decode"):
+                nxt_host = np.asarray(nxt)               # force the sync
         tokens = nxt[:, None].astype(tokens.dtype)
         for i in self.active():
             st = self.slots[i]
@@ -427,7 +441,9 @@ class ContinuousBatcher:
         with self.tracer.phase("compute", track="decode"):
             cache, res = self.spec.cycle(cache, tokens,
                                          active=self.active())
-            n_emit_host = np.asarray(res.n_emit)         # force the sync
+            with self.tracer.span("batcher.readback", cat="sync",
+                                  track="decode"):
+                n_emit_host = np.asarray(res.n_emit)     # force the sync
         tokens = res.next_tokens.astype(tokens.dtype)
         accepted = proposed = 0
         for i in self.active():
@@ -511,12 +527,10 @@ class ContinuousBatcher:
             while pending and self.free_slots() and arrived(pending[0]):
                 req = pending.pop(0)
                 try:
-                    with self.tracer.span(f"admit[{req.uid}]", cat="sched",
-                                          track="decode", uid=req.uid):
-                        cache, tokens = self.admit(
-                            cache, tokens, req.uid, req.prompt,
-                            req.max_new_tokens,
-                            session=getattr(req, "session", None))
+                    cache, tokens = self.admit(
+                        cache, tokens, req.uid, req.prompt,
+                        req.max_new_tokens,
+                        session=getattr(req, "session", None))
                     deferrals.pop(req.uid, None)
                 except PoolExhausted as e:
                     if not self.active():
@@ -560,14 +574,14 @@ class ContinuousBatcher:
                 # idle until the next arrival — a waiting engine burns
                 # neither decode steps nor the step budget
                 next_t = t_start + getattr(pending[0], "arrival_s", 0.0)
-                _time.sleep(min(max(next_t - clock(), 0.0), 0.005))
+                with self.tracer.span("batcher.wait_arrival", cat="sched",
+                                      track="decode"):
+                    _time.sleep(min(max(next_t - clock(), 0.0), 0.005))
                 if self.kv is not None and self.kv.parking:
                     self.kv.sweep_parked()
                 continue
             if self.kv is not None and self.kv.parking:
                 self.kv.sweep_parked()
-            if self.metrics is not None:
-                self.metrics.sample()
             steps += 1
         self._queued_n = 0
         self._deferred_n = 0

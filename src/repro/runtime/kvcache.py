@@ -699,7 +699,7 @@ class BlockOffloader:
         t_enter = clock()
         deadline = t_enter + timeout
         with self.tracer.phase("h2d", cat="kv", track="decode",
-                               min_dur=2e-4, label=f"kv_wait[{h}]"):
+                               min_dur=2e-4, label="kv_wait"):
             with self._cv:
                 while h not in self._staged:
                     if self._error is not None:
@@ -794,6 +794,7 @@ class KVStats:
     parked_sessions: int = 0          # lifetime park count
     restored_sessions: int = 0        # lifetime restore count
     budget_refusals: int = 0          # tier leases the budget refused
+    table_syncs: int = 0              # block_table/len round trips
 
     @property
     def highwater_bytes(self) -> int:
@@ -898,6 +899,7 @@ class PagedKVCache:
         self.ctx = self.max_pages * page_tokens
         self._spec = paged_cache_spec(cfg)
         self.dtype = dtype
+        self.tracer = tracer or NULL_TRACER
         self.memory = memory if memory is not None \
             else TierManager(tracer=tracer, name="kv-memory")
         if n_pages is None:
@@ -959,6 +961,9 @@ class PagedKVCache:
         self._active_tokens_hw = 0
         self.prefix_hits = 0
         self.cow_copies = 0
+        #: device round trips of the block table and lengths
+        #: (``_sync_tables`` with a dirty slot)
+        self.table_syncs = 0
 
     # -- construction ------------------------------------------------------ #
 
@@ -1012,7 +1017,8 @@ class PagedKVCache:
             parked_sessions=self.parked_count,
             restored_sessions=self.restored_count,
             budget_refusals=sum(
-                s.refusals for s in self.memory.stats().values()))
+                s.refusals for s in self.memory.stats().values()),
+            table_syncs=self.table_syncs)
 
     # -- cost-model eviction pricing --------------------------------------- #
 
@@ -1071,18 +1077,22 @@ class PagedKVCache:
         mirror and the device counter agree for every live slot."""
         if not self._dirty:
             return cache
-        table = np.asarray(cache["block_table"]).copy()
-        lens = np.asarray(cache["len"]).copy()
-        for slot in self._dirty:
-            row = np.full((self.max_pages,), SINK_PAGE, np.int32)
-            if slot not in self._chunking:       # mid-chunk: stay masked
-                pids = self._slot_pages[slot][:self.max_pages]
-                row[:len(pids)] = pids
-            table[slot] = row
-            lens[slot] = 0 if slot in self._chunking else self._len[slot]
-        self._dirty.clear()
-        return {**cache, "block_table": jnp.asarray(table),
-                "len": jnp.asarray(lens)}
+        self.table_syncs += 1
+        with self.tracer.span("kv.sync_tables", cat="kv", track="decode",
+                              slots=len(self._dirty)):
+            table = np.asarray(cache["block_table"]).copy()
+            lens = np.asarray(cache["len"]).copy()
+            for slot in self._dirty:
+                row = np.full((self.max_pages,), SINK_PAGE, np.int32)
+                if slot not in self._chunking:   # mid-chunk: stay masked
+                    pids = self._slot_pages[slot][:self.max_pages]
+                    row[:len(pids)] = pids
+                table[slot] = row
+                lens[slot] = 0 if slot in self._chunking \
+                    else self._len[slot]
+            self._dirty.clear()
+            return {**cache, "block_table": jnp.asarray(table),
+                    "len": jnp.asarray(lens)}
 
     # -- admit ------------------------------------------------------------- #
 
@@ -1119,65 +1129,68 @@ class PagedKVCache:
         pages in one ``install`` with no interleaving, so it registers
         eagerly and keeps the plan-to-install overlap.)
         """
-        bs = self.page_tokens
-        S, total = len(prompt), len(prompt) + max_new
-        if total > self.ctx:
-            raise ValueError(
-                f"request needs {total} positions (prompt {S} + max_new "
-                f"{max_new}) but the paged slot addresses only "
-                f"{self.ctx} ({self.max_pages} pages x {bs} tokens)")
-        if self._slot_pages[slot]:
-            raise RuntimeError(f"slot {slot} already holds pages")
-        # worst-case lifetime pages: every position paged, +1 for the
-        # copy-on-write clone of a shared divergence page
-        worst = -(-total // bs) + 1
-        committed = sum(self._reserved) + worst
-        if committed > self._usable:
-            raise PoolExhausted(
-                f"KV block pool exhausted: admitting would oversubscribe "
-                f"{committed}/{self._usable} pages "
-                f"({sum(1 for r in self._reserved if r)} slots live)")
-        n_blocks = -(-S // bs)
-        pids: List[int] = []
-        meta: List[Tuple[str, Any]] = []
-        h: tuple = ()
-        try:
-            for j in range(n_blocks):
-                toks = prompt[j * bs:(j + 1) * bs]
-                h = chain_key(h, toks, len(toks))
-                pid = self.pool.lookup(h)
-                if pid is not None:                      # resident hit
-                    self.pool.retain(pid)
-                    kind = "shared"
-                elif self.offloader is not None and self.offloader.holds(h):
-                    pid = self.pool.alloc(evict_cb=self._evict_cb(cache))
-                    self.offloader.schedule(h)
-                    self.pool.register(h, pid)
-                    self.pool.note_hit(h)    # off-device reuse: same
-                    kind = "fetched"         # frequency signal as lookup
-                else:
-                    pid = self.pool.alloc(evict_cb=self._evict_cb(cache))
-                    if register:
+        with self.tracer.span("kv.plan_admit", cat="kv", track="decode",
+                              slot=slot):
+            bs = self.page_tokens
+            S, total = len(prompt), len(prompt) + max_new
+            if total > self.ctx:
+                raise ValueError(
+                    f"request needs {total} positions (prompt {S} + max_new "
+                    f"{max_new}) but the paged slot addresses only "
+                    f"{self.ctx} ({self.max_pages} pages x {bs} tokens)")
+            if self._slot_pages[slot]:
+                raise RuntimeError(f"slot {slot} already holds pages")
+            # worst-case lifetime pages: every position paged, +1 for the
+            # copy-on-write clone of a shared divergence page
+            worst = -(-total // bs) + 1
+            committed = sum(self._reserved) + worst
+            if committed > self._usable:
+                raise PoolExhausted(
+                    f"KV block pool exhausted: admitting would oversubscribe "
+                    f"{committed}/{self._usable} pages "
+                    f"({sum(1 for r in self._reserved if r)} slots live)")
+            n_blocks = -(-S // bs)
+            pids: List[int] = []
+            meta: List[Tuple[str, Any]] = []
+            h: tuple = ()
+            try:
+                for j in range(n_blocks):
+                    toks = prompt[j * bs:(j + 1) * bs]
+                    h = chain_key(h, toks, len(toks))
+                    pid = self.pool.lookup(h)
+                    if pid is not None:                      # resident hit
+                        self.pool.retain(pid)
+                        kind = "shared"
+                    elif self.offloader is not None \
+                            and self.offloader.holds(h):
+                        pid = self.pool.alloc(evict_cb=self._evict_cb(cache))
+                        self.offloader.schedule(h)
                         self.pool.register(h, pid)
-                    kind = "fresh"
-                pids.append(pid)
-                meta.append((kind, h))
-        except PoolExhausted:
-            # roll the reservation back whole: pages registered for this
-            # admit were never filled, so they must not survive into the
-            # prefix cache
-            for pid, (kind, _) in zip(pids, meta):
-                if kind != "shared":
-                    self.pool.unregister(pid)
-                self.pool.release(pid)
-            raise
-        self.prefix_hits += sum(1 for k, _ in meta if k != "fresh")
-        self._slot_pages[slot] = pids
-        self._admit_meta[slot] = meta
-        self._reserved[slot] = worst
-        self._dirty.add(slot)
-        return {k: sum(1 for kk, _ in meta if kk == k)
-                for k in ("shared", "fetched", "fresh")}
+                        self.pool.note_hit(h)    # off-device reuse: same
+                        kind = "fetched"         # frequency signal as lookup
+                    else:
+                        pid = self.pool.alloc(evict_cb=self._evict_cb(cache))
+                        if register:
+                            self.pool.register(h, pid)
+                        kind = "fresh"
+                    pids.append(pid)
+                    meta.append((kind, h))
+            except PoolExhausted:
+                # roll the reservation back whole: pages registered for this
+                # admit were never filled, so they must not survive into the
+                # prefix cache
+                for pid, (kind, _) in zip(pids, meta):
+                    if kind != "shared":
+                        self.pool.unregister(pid)
+                    self.pool.release(pid)
+                raise
+            self.prefix_hits += sum(1 for k, _ in meta if k != "fresh")
+            self._slot_pages[slot] = pids
+            self._admit_meta[slot] = meta
+            self._reserved[slot] = worst
+            self._dirty.add(slot)
+            return {k: sum(1 for kk, _ in meta if kk == k)
+                    for k in ("shared", "fetched", "fresh")}
 
     def abort_admit(self, slot: int) -> None:
         """Undo a ``plan_admit`` whose prefill failed: return the slot's
@@ -1309,36 +1322,37 @@ class PagedKVCache:
         pages in the write range, unregister hashes of private pages
         about to be written, and flush table/len cleanup of freed slots.
         """
-        bs = self.page_tokens
-        for slot in active:
-            ln = self._len[slot]
-            need = -(-(ln + n_tokens) // bs)
-            if need > self.max_pages:
-                raise PoolExhausted(
-                    f"slot {slot} needs {need} pages "
-                    f"(len {ln} + {n_tokens}) > table width "
-                    f"{self.max_pages}")
-            pids = self._slot_pages[slot]
-            while len(pids) < need:
-                pids.append(self.pool.alloc(
-                    evict_cb=self._evict_cb(cache)))
-                self._dirty.add(slot)
-            first_blk = ln // bs
-            last_blk = (ln + n_tokens - 1) // bs
-            for j in range(first_blk, last_blk + 1):
-                pid = pids[j]
-                if self.pool.refcount(pid) > 1:           # divergence: CoW
-                    new = self.pool.alloc(evict_cb=self._evict_cb(cache))
-                    cache = self._copy_page(cache, pid, new)
-                    self.pool.release(pid)
-                    pids[j] = new
-                    self.cow_copies += 1
+        with self.tracer.span("kv.begin_step", cat="kv", track="decode"):
+            bs = self.page_tokens
+            for slot in active:
+                ln = self._len[slot]
+                need = -(-(ln + n_tokens) // bs)
+                if need > self.max_pages:
+                    raise PoolExhausted(
+                        f"slot {slot} needs {need} pages "
+                        f"(len {ln} + {n_tokens}) > table width "
+                        f"{self.max_pages}")
+                pids = self._slot_pages[slot]
+                while len(pids) < need:
+                    pids.append(self.pool.alloc(
+                        evict_cb=self._evict_cb(cache)))
                     self._dirty.add(slot)
-                else:
-                    self.pool.unregister(pid)     # content will change
-        cache = self._sync_tables(cache)
-        self._note_highwater()
-        return cache
+                first_blk = ln // bs
+                last_blk = (ln + n_tokens - 1) // bs
+                for j in range(first_blk, last_blk + 1):
+                    pid = pids[j]
+                    if self.pool.refcount(pid) > 1:           # divergence: CoW
+                        new = self.pool.alloc(evict_cb=self._evict_cb(cache))
+                        cache = self._copy_page(cache, pid, new)
+                        self.pool.release(pid)
+                        pids[j] = new
+                        self.cow_copies += 1
+                        self._dirty.add(slot)
+                    else:
+                        self.pool.unregister(pid)     # content will change
+            cache = self._sync_tables(cache)
+            self._note_highwater()
+            return cache
 
     def advance(self, slot: int, n: int = 1) -> None:
         """Commit ``n`` generated tokens (vanilla decode bookkeeping)."""

@@ -341,8 +341,10 @@ class MetricsRegistry:
         return out
 
     def prometheus_text(self) -> str:
-        """Prometheus text exposition: counters as ``_total``, histograms
-        as summaries (quantile labels + ``_sum``/``_count``)."""
+        """Prometheus text exposition: counters as ``_total``, gauges as
+        sampled now, histograms as summaries (quantile labels +
+        ``_sum``/``_count``)."""
+        self.sample()
         lines: List[str] = []
         with self._lock:
             counters = list(self._counters.values())
@@ -440,8 +442,9 @@ class RequestTracker:
     """Per-request lifecycle recorder bound to a registry.
 
     The engine calls ``submit`` when a request becomes visible (its
-    arrival time passes, or it enters the admit loop), ``admitted`` when
-    a slot is claimed (queue wait observed; ``restored=True`` marks a
+    arrival time passes, or it enters the admit loop), ``admitted`` once
+    a slot is claimed, stamped with the start of that admit (queue wait
+    observed, the prefill not in it; ``restored=True`` marks a
     parked-session restore), ``token`` per emitted token (the first one
     stamps TTFT), ``finished``/``rejected`` to close the trace. All
     methods are idempotent-friendly and no-ops for unknown uids, so the
@@ -461,11 +464,14 @@ class RequestTracker:
             prompt_len=prompt_len)
         self.reg.inc("requests/submitted")
 
-    def admitted(self, uid: int, *, restored: bool = False) -> None:
+    def admitted(self, uid: int, *, t: Optional[float] = None,
+                 restored: bool = False) -> None:
+        """A slot was claimed; ``t`` is when its admit began (default:
+        now), so the queue wait excludes the prefill."""
         tr = self._live.get(uid)
         if tr is None:
             return
-        tr.admit_t = clock()
+        tr.admit_t = clock() if t is None else t
         tr.restored = restored
         self.reg.inc("requests/admitted")
         if restored:

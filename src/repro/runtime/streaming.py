@@ -315,7 +315,7 @@ class LayerPrefetcher:
             # span itself only traces when the wait actually stalled)
             with self.tracer.phase("disk_wait", cat="prefetch",
                                    track="decode", min_dur=2e-4,
-                                   label=f"disk_wait[{i}]"):
+                                   label="disk_wait", layer=i):
                 while i not in self._buf:
                     if self._error is not None:
                         raise RuntimeError(
@@ -579,8 +579,8 @@ class RingBankPrefetcher:
     def _build_bank(self, t: int):
         rows = self._rows[t]
         layers = [self._layer_np(int(i)) for i in rows]
-        with self.tracer.span(f"bank_h2d[{t}]", cat="prefetch",
-                              track="ring-prefetcher"):
+        with self.tracer.span("bank_h2d", cat="prefetch",
+                              track="ring-prefetcher", bank=t):
             bank_np = jax.tree.map(lambda *xs: np.stack(xs, 0), *layers)
             nbytes = sum(a.nbytes for a in jax.tree.leaves(bank_np))
             # device bytes for the stacked bank: leased before the put,
@@ -661,7 +661,7 @@ class RingBankPrefetcher:
             t0 = clock()
             with self.tracer.phase("disk_wait", cat="prefetch",
                                    track="decode", min_dur=2e-4,
-                                   label=f"bank_wait[{t}]"):
+                                   label="bank_wait", bank=t):
                 while t not in self._banks:
                     if self._error is not None:
                         raise RuntimeError(
@@ -783,8 +783,7 @@ class StreamingRingDriver:
         spans land on the ``ring`` track of the exported trace.
         """
         with self.tracer.token_step(self._token_idx, track="decode",
-                                    name=f"ring_token"
-                                         f"[{self._token_idx}]"):
+                                    name="ring_token"):
             self._token_idx += 1
             return self._step_inner(tokens, ln, cache)
 
@@ -804,7 +803,7 @@ class StreamingRingDriver:
         for t in range(self.n_steps):
             bank = self.prefetch.get(t)
             with self.tracer.phase("compute", cat="ring", track="ring",
-                                   label=f"microstep[{t}]"):
+                                   label="microstep", microstep=t):
                 x, layers_c, out_buf = self._micro(
                     jnp.int32(t), x, emb_all, ln, layers_c, out_buf,
                     bank, self.head_params["final_norm"])

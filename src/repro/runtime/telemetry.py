@@ -15,8 +15,8 @@ shared measurement substrate:
     different subsystems merge into one ordered timeline.
   * **a tracer** — :class:`Tracer`: a thread-safe *bounded ring buffer*
     of typed events (:class:`SpanEvent` / :class:`CounterEvent` /
-    :class:`InstantEvent`). Near-zero overhead when disabled (one
-    attribute check per call site, no allocation, no lock); optional
+    :class:`InstantEvent`). Near-zero overhead when disabled (a flag
+    and a profiler-session check per call site, no lock); optional
     deterministic 1-in-N sampling when enabled. The buffer never grows
     past ``capacity`` — a week-long serve cannot OOM on its own
     telemetry; ``evicted`` counts what wrapped away.
@@ -33,15 +33,23 @@ shared measurement substrate:
     :meth:`Tracer.export_chrome_trace` emit Chrome Trace Event Format
     JSON (one track per worker thread / ring stage) that loads directly
     in Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.
+  * **the profiler's clock** — while a ``jax.profiler`` session records,
+    every :meth:`Tracer.span`, :meth:`Tracer.token_step` and
+    :meth:`Tracer.phase` is also a ``TraceAnnotation`` of the same name
+    (its args as the event's stats), on any tracer, :data:`NULL_TRACER`
+    included. The spans then lie on the device trace's timeline. With no
+    session a span site costs one ``is_enabled()`` check. Span names are
+    static; what varies (uid, step index) goes in args.
+  * **a compile log** — :func:`compile_log`: each jaxpr trace, MLIR
+    lowering and backend compile (or persistent-cache load) of the
+    process, stamped on :func:`clock`.
 
-Legacy record types (``PrefetchEvent`` timelines, ``FiredFault`` audit
-trails, ``FailoverEvent`` recovery splits, ``WorkerHealth``) are
-subsumed via the ``ingest_*`` adapters — they become spans/instants on
-the shared timeline — while the hot paths also emit live when a tracer
-is attached. ``core.latency.telemetry_crosscheck`` closes the loop by
-comparing the measured per-term splits against the Halda latency
-model's disk/compute/comms terms (the drift signal ROADMAP item 4's
-online re-solve consumes).
+``FailoverEvent`` recovery splits become spans on the shared timeline
+through :meth:`Tracer.ingest_failover_event`, while the hot paths emit
+live when a tracer is attached. ``core.latency.telemetry_crosscheck``
+closes the loop by comparing the measured per-term splits against the
+Halda latency model's disk/compute/comms terms (the drift signal
+ROADMAP item 4's online re-solve consumes).
 
 Validator CLI (used by CI's trace smoke)::
 
@@ -56,8 +64,11 @@ import logging
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple, Union
+from contextlib import contextmanager, nullcontext
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
+
+from jax import monitoring
+from jax.profiler import TraceAnnotation
 
 #: the one runtime clock. Monotonic, high resolution, and — crucially —
 #: the SAME base every subsystem stamps against (``PrefetchEvent``
@@ -235,12 +246,12 @@ class Tracer:
     """Thread-safe bounded-ring-buffer span/counter tracer.
 
     ``enabled=False`` (or :data:`NULL_TRACER`) is the production default:
-    every emission path checks the flag first and returns without
-    allocating or locking, so instrumentation can stay compiled into the
-    hot paths permanently. ``sample=1/N`` keeps every N-th event
-    (deterministic — no RNG), bounding trace size on long serves while
-    stall attribution (which aggregates, not stores-per-event) stays
-    exact.
+    every emission path checks the flag (and, for spans, whether a
+    profiler session records) first and returns without locking, so
+    instrumentation can stay compiled into the hot paths permanently.
+    ``sample=1/N`` keeps every N-th event (deterministic — no RNG),
+    bounding trace size on long serves while stall attribution (which
+    aggregates, not stores-per-event) stays exact.
     """
 
     def __init__(self, *, enabled: bool = True, capacity: int = 65536,
@@ -309,35 +320,46 @@ class Tracer:
     @contextmanager
     def span(self, name: str, *, cat: str = "span",
              track: Optional[str] = None, **args):
-        """Time a block as one span. No-op (no clock reads) when
-        disabled."""
-        if not self.enabled:
-            yield
+        """Time a block as one span: on the ring buffer when enabled, and
+        as a ``TraceAnnotation`` while a profiler session records. Yields
+        a dict the block may add args to, or None where neither records
+        (then no clock is read)."""
+        ann = _annotation(name, args)
+        if not self.enabled and ann is _NO_ANNOTATION:
+            yield None
             return
+        more: Dict[str, Any] = {}
         t0 = clock()
-        try:
-            yield
-        finally:
-            self.span_event(name, t0, clock(), cat=cat, track=track,
-                            **args)
+        with ann:
+            try:
+                yield more
+            finally:
+                if more and ann is not _NO_ANNOTATION:
+                    ann.set_metadata(**more)
+                if self.enabled:
+                    self.span_event(name, t0, clock(), cat=cat,
+                                    track=track, **args, **more)
 
     # -- stall attribution ------------------------------------------------- #
 
     @contextmanager
     def token_step(self, index: int, *, track: str = "decode",
-                   name: Optional[str] = None, **args):
+                   name: str = "token", **args):
         """Open a per-token step scope on this thread. ``phase()`` calls
         underneath (in this thread) attribute into it; on exit a
         :class:`StallRecord` is appended and the step is emitted as a
-        span on the ``track`` timeline."""
+        span ``name`` (arg ``index``) on the ``track`` timeline."""
+        ann = _annotation(name, args, index=index)
         if not self.enabled:
-            yield None
+            with ann:
+                yield None
             return
         prev = getattr(self._local, "step", None)
         step = TokenStep(index, track, clock())
         self._local.step = step
         try:
-            yield step
+            with ann:
+                yield step
         finally:
             t_end = clock()
             self._local.step = prev
@@ -346,8 +368,8 @@ class Tracer:
                 if len(self._stalls) == self.capacity:
                     self.stalls_evicted += 1
                 self._stalls.append(rec)
-            self.span_event(name or f"token[{index}]", step.t_start,
-                            t_end, cat="decode", track=track,
+            self.span_event(name, step.t_start, t_end, cat="decode",
+                            track=track, index=index,
                             disk_wait_ms=round(rec.disk_wait_s * 1e3, 3),
                             compute_ms=round(rec.compute_s * 1e3, 3),
                             **args)
@@ -365,18 +387,21 @@ class Tracer:
         duration lands on that step's record; a span is also emitted
         (named ``label`` if given, suppressed under ``min_dur`` — e.g.
         the prefetcher's usually-instant ``disk_wait`` waits only trace
-        when they actually stalled). Disabled tracer: straight
-        passthrough.
+        when they actually stalled; a profiler annotation is never
+        suppressed). Disabled tracer: straight passthrough.
         """
+        ann = _annotation(label or name, args)
         if not self.enabled:
-            yield
+            with ann:
+                yield
             return
         step = getattr(self._local, "step", None)
         t0 = clock()
         if step is not None:
             step.enter_phase(name, t0)
         try:
-            yield
+            with ann:
+                yield
         finally:
             t1 = clock()
             if step is not None:
@@ -415,33 +440,7 @@ class Tracer:
             self.evicted = 0
             self.stalls_evicted = 0
 
-    # -- legacy-record ingestion (schema subsumption) ----------------------- #
-
-    def ingest_prefetch_events(self, events: Iterable, *,
-                               track: str = "prefetcher",
-                               cat: str = "prefetch",
-                               name: str = "layer_read") -> int:
-        """Merge a ``PrefetchEvent`` timeline (layer prefetcher, ring
-        bank prefetcher, or KV offloader — they share the record type and
-        the clock) onto the trace as spans. Returns events ingested."""
-        n = 0
-        for e in events:
-            self.span_event(f"{name}[{e.layer}]", e.t_start, e.t_end,
-                            cat=cat, track=track, nbytes=e.nbytes)
-            n += 1
-        return n
-
-    def ingest_fired_faults(self, fired: Iterable, *,
-                            track: str = "faults") -> int:
-        """``faults.FiredFault`` audit trail -> instant events (same
-        clock since the fault injector stamps with ``telemetry.clock``)."""
-        n = 0
-        for f in fired:
-            self.instant(f"fault:{f.mode}:{f.op}", cat="fault",
-                         track=track, t=f.t, key=f.key,
-                         call_index=f.call_index)
-            n += 1
-        return n
+    # -- legacy-record ingestion ------------------------------------------- #
 
     def ingest_failover_event(self, ev, *, t_end: Optional[float] = None,
                               track: str = "failover") -> None:
@@ -458,16 +457,6 @@ class Tracer:
                             track=track, token_index=ev.token_index,
                             failed_stage=ev.failed_stage,
                             stages_after=ev.n_stages_after)
-
-    def ingest_worker_health(self, health, *,
-                             track: Optional[str] = None) -> None:
-        """``iopolicy.WorkerHealth`` -> an instant + counters on the
-        worker's own track (same clock as of this PR)."""
-        tr = track or health.name or "worker"
-        self.instant(f"health:{health.report()}", cat="health", track=tr,
-                     t=health.last_progress_t)
-        self.counter("retries", health.retries, track=tr)
-        self.counter("failures", health.failures, track=tr)
 
     # -- Chrome trace (Perfetto) export ------------------------------------ #
 
@@ -533,9 +522,93 @@ def _thread_track() -> str:
     return threading.current_thread().name
 
 
+_NO_ANNOTATION = nullcontext()
+
+
+def _annotation(name: str, args: Dict[str, Any], **more):
+    """The span's ``TraceAnnotation`` while a profiler session records,
+    else a shared no-op context (no annotation made, nothing formatted)."""
+    if TraceAnnotation.is_enabled():
+        return TraceAnnotation(name, **args, **more)
+    return _NO_ANNOTATION
+
+
 #: the shared disabled tracer: instrumented code defaults to it so the
 #: hot paths never branch on ``None``.
 NULL_TRACER = Tracer(enabled=False, capacity=1)
+
+
+# --------------------------------------------------------------------------- #
+#  compile log (where set-up goes, which call recompiled)
+# --------------------------------------------------------------------------- #
+
+#: ``jax.monitoring`` time-span events of building a program -> kind
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # a load from the persistent compilation cache is timed here too
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+
+
+@dataclasses.dataclass(frozen=True)
+class CompileSpan:
+    kind: str                     # trace | lower | compile
+    fun: str                      # the traced function or module
+    t_start: float                # on ``clock``
+    t_end: float
+
+
+class CompileLog:
+    """The process's program builds on :func:`clock`: a
+    :class:`CompileSpan` per jaxpr trace, MLIR lowering and backend
+    compile. Bounded like the tracer's ring; ``t_start`` is when it
+    began to listen. Get the process's one log from
+    :func:`compile_log`."""
+
+    def __init__(self, capacity: int = 65536):
+        self.t_start = clock()
+        self.spans: deque = deque(maxlen=capacity)
+
+    def _on_span(self, event: str, start: float, end: float,
+                 fun_name: str = "", **_) -> None:
+        kind = COMPILE_EVENTS.get(event)
+        if kind is not None:
+            # jax times the span on the wall clock and reports it as it
+            # ends: place it on ``clock`` by its end
+            t1 = clock()
+            self.spans.append(CompileSpan(kind, str(fun_name),
+                                          t1 - (end - start), t1))
+
+    def seconds(self, kinds: Sequence[str], t_end: float = float("inf")
+                ) -> float:
+        """Seconds spent in spans of ``kinds`` that ended by ``t_end``; a
+        trace nested in another (a jitted callee traced inside its
+        caller) counts once."""
+        iv = sorted((s.t_start, s.t_end) for s in list(self.spans)
+                    if s.kind in kinds and s.t_end <= t_end)
+        total, hi = 0.0, float("-inf")
+        for a, b in iv:
+            if b > hi:
+                total += b - max(a, hi)
+                hi = b
+        return total
+
+
+_compile_log: Optional[CompileLog] = None
+_compile_log_lock = threading.Lock()
+
+
+def compile_log() -> CompileLog:
+    """The process's compile log, listening from the first call on
+    (engine builds make that call)."""
+    global _compile_log
+    with _compile_log_lock:
+        if _compile_log is None:
+            log = CompileLog()
+            monitoring.register_event_time_span_listener(log._on_span)
+            _compile_log = log
+        return _compile_log
 
 
 # --------------------------------------------------------------------------- #

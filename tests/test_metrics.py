@@ -364,6 +364,33 @@ def test_engine_respect_arrivals_replays_queue_wait():
     assert all(t.queue_wait_s >= 0 for t in traces.values())
 
 
+def test_queue_wait_excludes_the_prefill():
+    """``admit_t`` is the start of the admit: a request admitted at once
+    waits ~0 however long its prefill takes, and the prefill lands in
+    ``request/prefill_s`` and TTFT instead."""
+    import time
+
+    from repro.runtime.engine import ContinuousBatcher
+
+    prefill_s, vocab = 0.05, 8
+
+    def prefill_one(prompt):
+        time.sleep(prefill_s)
+        return 1, None
+
+    def decode(cache, tokens):
+        return jnp.zeros((tokens.shape[0], 1, vocab)), cache
+
+    reg = MetricsRegistry()
+    eng = ContinuousBatcher(1, prefill_one, lambda c, *_: c, decode,
+                            metrics=reg)
+    eng.run(None, [_Req(0, np.arange(4), 2)])
+    (trace,) = reg.request_log
+    assert trace.queue_wait_s < prefill_s / 2
+    assert trace.ttft_s >= prefill_s
+    assert reg.histogram("request/prefill_s").quantile(0.5) >= prefill_s
+
+
 def test_paged_engine_gauges_and_session_restore_counter(tmp_path):
     cfg = _cfg()
     params = init_params(cfg, KEY)

@@ -338,27 +338,8 @@ def test_fault_injector_emits_live_instants():
 
 
 # --------------------------------------------------------------------------- #
-#  ingestion adapters (legacy-record subsumption)
+#  ingestion adapters
 # --------------------------------------------------------------------------- #
-
-def test_ingest_prefetch_and_health_and_faults():
-    from repro.runtime.iopolicy import WorkerHealth
-    from repro.runtime.streaming import PrefetchEvent
-
-    tr = Tracer()
-    n = tr.ingest_prefetch_events(
-        [PrefetchEvent(0, 1.0, 2.0, 100), PrefetchEvent(1, 2.0, 3.0, 100)])
-    assert n == 2
-    spans = [e for e in tr.events() if isinstance(e, SpanEvent)]
-    assert [s.name for s in spans] == ["layer_read[0]", "layer_read[1]"]
-    assert all(s.track == "prefetcher" for s in spans)
-
-    h = WorkerHealth(name="LayerPrefetcher")
-    h.retries = 3
-    tr.ingest_worker_health(h)
-    counters = [e for e in tr.events() if isinstance(e, CounterEvent)]
-    assert any(c.name == "retries" and c.value == 3.0 for c in counters)
-
 
 def test_ingest_failover_event_splits():
     from repro.runtime.failover import FailoverEvent
@@ -499,7 +480,7 @@ def test_telemetry_crosscheck_comms_term():
 
 
 # --------------------------------------------------------------------------- #
-#  engine integration: token steps + telemetry() accessor
+#  streaming sources: stall surfaced through stats()
 # --------------------------------------------------------------------------- #
 
 def test_prefetcher_stats_surface_stall_uniformly(tmp_path):
