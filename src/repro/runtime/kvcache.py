@@ -1321,8 +1321,13 @@ class PagedKVCache:
         writable: grow page lists across boundaries, copy-on-write shared
         pages in the write range, unregister hashes of private pages
         about to be written, and flush table/len cleanup of freed slots.
+
+        While the span records, its args ``live_pages`` (pages the active
+        slots hold) and ``table_pages`` (every entry of the block table)
+        say how much of the table the step's attention has to read.
         """
-        with self.tracer.span("kv.begin_step", cat="kv", track="decode"):
+        with self.tracer.span("kv.begin_step", cat="kv",
+                              track="decode") as span:
             bs = self.page_tokens
             for slot in active:
                 ln = self._len[slot]
@@ -1352,6 +1357,10 @@ class PagedKVCache:
                         self.pool.unregister(pid)     # content will change
             cache = self._sync_tables(cache)
             self._note_highwater()
+            if span is not None:
+                span["live_pages"] = sum(len(self._slot_pages[s])
+                                         for s in active)
+                span["table_pages"] = self.B * self.max_pages
             return cache
 
     def advance(self, slot: int, n: int = 1) -> None:

@@ -99,7 +99,8 @@ def test_profiler_trace_holds_each_static_span(model, tmp_path, tracer):
     plane, whether the engine's tracer is the disabled default or a
     recording one: one ``batcher.step`` per decode step, one
     ``kv.sync_tables`` per table round trip, one ``batcher.admit`` per
-    admit, with their args as stats and no value in a name."""
+    admit, with their args as stats and no value in a name; each
+    ``kv.begin_step`` carries the pages its step's slots hold."""
     cfg, params = model
     tr = Tracer() if tracer else None
     jax.profiler.start_trace(str(tmp_path))
@@ -119,6 +120,10 @@ def test_profiler_trace_holds_each_static_span(model, tmp_path, tracer):
     assert admits == [(0, 19, 0), (1, 19, 24), (2, 13, 0), (3, 9, 0)]
     steps = [s["index"] for name, s in events if name == "batcher.step"]
     assert sorted(steps) == list(range(eng._step_idx))
+    # each step's page count: two slots of 64 / 8 = 8 table pages
+    begins = [s for name, s in events if name == "kv.begin_step"]
+    assert all(s["table_pages"] == 16 and 0 < s["live_pages"] <= 16
+               for s in begins), begins
     if tr is not None:
         ring = {e.name for e in tr.events()}
         assert set(SPANS) <= ring

@@ -7,8 +7,9 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.flash_decode import flash_decode, flash_verify
-from repro.kernels.paged_decode import (paged_decode, paged_decode_quant,
-                                        paged_verify, paged_verify_quant)
+from repro.kernels.paged_decode import (_block_pages, paged_decode,
+                                        paged_decode_quant, paged_verify,
+                                        paged_verify_quant)
 from repro.kernels.paged_prefill import paged_prefill
 from repro.kernels.q4_matmul import q4_matmul
 from repro.kernels.ssd_scan import ssd_scan
@@ -108,11 +109,14 @@ def test_flash_verify_T1_matches_flash_decode():
     (2, 8, 2, 64, 16, 16, 4),
     (1, 4, 4, 128, 8, 32, 3),    # MHA
     (3, 8, 1, 64, 32, 8, 6),     # MQA, small pages
+    (2, 40, 8, 128, 48, 16, 20),   # qwen2.5-14b heads; 20 pages = 16 + 4
+    (2, 32, 8, 128, 48, 16, 20),   # mixtral-8x7b heads
 ])
 def test_paged_verify_sweep(T, B, H, hkv, D, P, bs, nb):
-    """Paged verify kernel (block-table gather through scalar prefetch)
-    vs the gather-then-verify oracle; tables are random permutations so
-    physical != logical page order."""
+    """Paged verify kernel (pages fetched through the scalar-prefetched
+    block table) vs the gather-then-verify oracle; tables are random
+    permutations so physical != logical page order. The last two shapes
+    span two multi-page blocks, the second one partial."""
     q = jax.random.normal(KEY, (B, T, H, D))
     kp = jax.random.normal(jax.random.PRNGKey(2), (P, bs, hkv, D))
     vp = jax.random.normal(jax.random.PRNGKey(3), (P, bs, hkv, D))
@@ -146,6 +150,77 @@ def test_paged_verify_window_and_T1_decode():
     want1 = ref.paged_decode_ref(q[:, 0], kp, vp, table, kv_len)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(want1),
                                rtol=1e-5, atol=1e-5)
+
+
+def _paged_case(T, kv_len, *, H=40, hkv=8, D=128, bs=16, nb=20, seed=0):
+    """Pools with one page more than the table reaches, a permuted
+    table, and q, for ``len(kv_len)`` slots (qwen2.5-14b's heads)."""
+    B = len(kv_len)
+    P = B * nb + 1
+    q = jax.random.normal(jax.random.PRNGKey(seed), (B, T, H, D))
+    kp = jax.random.normal(jax.random.PRNGKey(seed + 1), (P, bs, hkv, D))
+    vp = jax.random.normal(jax.random.PRNGKey(seed + 2), (P, bs, hkv, D))
+    table = np.random.default_rng(seed).permutation(P)[:B * nb] \
+        .reshape(B, nb).astype(np.int32)
+    return q, kp, vp, table, jnp.asarray(kv_len, jnp.int32)
+
+
+@pytest.mark.parametrize("T", [1, 4])
+@pytest.mark.parametrize("kv_len", [
+    (4, 9),          # inside the first page
+    (256, 257),      # on the edge of the first 16-page block, and past it
+    (320, 255),      # the whole table (nb * bs), and one short of a block
+])
+def test_paged_verify_lengths(T, kv_len):
+    q, kp, vp, table, kv = _paged_case(T, kv_len)
+    out = paged_verify(q, kp, vp, jnp.asarray(table), kv, interpret=True)
+    want = ref.paged_verify_ref(q, kp, vp, jnp.asarray(table), kv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-3, atol=1e-3)
+
+
+@pytest.mark.parametrize("T", [1, 4])
+def test_paged_verify_window_skips_whole_blocks(T):
+    """A 40-token window at 300 and 320 positions starts past the first
+    block of pages, so that block is never read: its pages hold NaN
+    here, and the output still equals the oracle's on the clean pools,
+    which masks them position by position."""
+    q, kp, vp, table, kv = _paged_case(T, (320, 300))
+    bs, n_rep = kp.shape[1], q.shape[2] // kp.shape[2]
+    ppb = _block_pages(bs, table.shape[1], kp.shape[2], kp.shape[3],
+                       kp.dtype.itemsize, T * n_rep)
+    # blocks wholly before the oldest position any draft row sees
+    skipped = [table[b, :(int(n) - T - 40 + 1) // (ppb * bs) * ppb]
+               for b, n in enumerate(np.asarray(kv))]
+    dead = np.concatenate(skipped)
+    assert all(len(p) for p in skipped)
+    out = paged_verify(q, kp.at[dead].set(jnp.nan),
+                       vp.at[dead].set(jnp.nan), jnp.asarray(table), kv,
+                       window=40, interpret=True)
+    want = ref.paged_verify_ref(q, kp, vp, jnp.asarray(table), kv,
+                                window=40)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-3, atol=1e-3)
+
+
+@pytest.mark.parametrize("T,window", [(1, None), (4, None), (4, 40)])
+def test_paged_verify_never_reads_unreached_pages(T, window):
+    """Every page the table does not reach below ``kv_len`` (the rest of
+    the table, the spare page, and in a live block the pages past the
+    slot's last) holds NaN: the output stays finite and equals the
+    oracle on the clean pools, so dead pages are skipped, not masked."""
+    q, kp, vp, table, kv = _paged_case(T, (37, 300, 256))
+    reached = {int(table[b, i]) for b, n in enumerate(np.asarray(kv))
+               for i in range(-(-int(n) // kp.shape[1]))}
+    dead = np.array(sorted(set(range(kp.shape[0])) - reached))
+    out = paged_verify(q, kp.at[dead].set(jnp.nan),
+                       vp.at[dead].set(jnp.nan), jnp.asarray(table), kv,
+                       window=window, interpret=True)
+    want = ref.paged_verify_ref(q, kp, vp, jnp.asarray(table), kv,
+                                window=window)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-3, atol=1e-3)
 
 
 def test_paged_verify_contiguous_table_matches_flash_verify():

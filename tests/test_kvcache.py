@@ -387,3 +387,37 @@ def test_paged_cache_rejects_recurrent_and_int8_mla():
     mla8 = dataclasses.replace(mla, kv_dtype="int8")
     with pytest.raises(NotImplementedError):
         PagedKVCache(mla8, batch=1, ctx=32, n_pages=8)
+
+
+def test_begin_step_span_counts_live_and_table_pages():
+    """A recorded ``kv.begin_step`` span carries the pages the active
+    slots hold after growth and the table's size: here slot 0 holds 2
+    pages (14 tokens + 1 of 8-token pages), slot 1 holds 1 (7 + 1), and
+    the table is 3 slots of 8 pages; an idle slot adds nothing."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.runtime.kvcache import PagedKVCache
+    from repro.runtime.telemetry import Tracer
+
+    cfg = dataclasses.replace(get_config("qwen2.5-14b").reduced(),
+                              n_layers=2)
+    L, hk, hd = cfg.n_layers, cfg.kv_heads, cfg.head_dim
+    fake = {"k": np.zeros((L, 1, 64, hk, hd), np.float32),
+            "v": np.zeros((L, 1, 64, hk, hd), np.float32)}
+    tr = Tracer()
+    kv = PagedKVCache(cfg, batch=3, ctx=64, n_pages=16, page_tokens=8,
+                      offload=False, tracer=tr)
+    cache = kv.init_cache()
+    try:
+        for slot, n in ((0, 14), (1, 7)):
+            kv.plan_admit(cache, slot, list(range(100 * slot,
+                                                  100 * slot + n)), 4)
+            cache = kv.install(cache, slot, fake, n)
+        cache = kv.begin_step(cache, [0, 1], 1)
+        cache = kv.begin_step(cache, [1], 1)
+    finally:
+        kv.close()
+    spans = [dict(e.args) for e in tr.events() if e.name == "kv.begin_step"]
+    assert [(s["live_pages"], s["table_pages"]) for s in spans] \
+        == [(3, 24), (1, 24)]

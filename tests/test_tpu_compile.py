@@ -73,6 +73,24 @@ def test_paged_verify_compiles(one_chip, n_draft):
         *_table(s, B), interpret=False))
 
 
+@pytest.mark.parametrize("n_draft", [1, 4])
+@pytest.mark.parametrize("batch,heads,n_pages", [
+    (16, 40, 2705),          # the qwen2.5-14b-l12 chat cell's engine
+    (26, 32, 4315),          # the mixtral-8x7b-l4 chat cell's engine
+], ids=["qwen", "mixtral"])
+def test_paged_verify_compiles_at_cell_shapes(one_chip, batch, heads,
+                                              n_pages, n_draft):
+    """The served shapes: 160-page tables over the cells' whole pools,
+    which the kernel reads in place through its page DMAs."""
+    s = one_chip
+    q = _spec(s, (batch, n_draft, heads, D), jnp.bfloat16)
+    pages = _spec(s, (n_pages, PAGE, HKV, D), jnp.bfloat16)
+    table = _spec(s, (batch, 160), jnp.int32)
+    _assert_kernel(paged_verify.lower(
+        q, pages, pages, table, _spec(s, (batch,), jnp.int32),
+        interpret=False))
+
+
 def test_paged_decode_compiles(one_chip):
     s = one_chip
     q = _spec(s, (B, H, D), jnp.bfloat16)
