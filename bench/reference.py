@@ -1,17 +1,13 @@
-"""The plain reference: the published forward pass in float32.
+"""The plain reference: the published forward pass in float32, and the
+comparison of served tokens against it.
 
-Qwen2 and Mixtral decoder layers as their papers and Hugging Face
-modelling code describe them: RMSNorm, q/k/v projections (with bias for
-Qwen2), rotary embeddings (rotate-half, inverse frequencies
-theta^(-2i/d)), grouped-query causal attention, SiLU-gated FFN, or for
-Mixtral a softmax router whose top-k gates are renormalised and weight
-the chosen experts' SiLU-gated FFNs. Matrix products run at the highest
-precision. It reads the benchmark's weight tree and imports nothing of
-the serving program.
-
-Memory: one layer (for MoE one expert) is upcast at a time, attention
-runs over blocks of queries, and the output head over blocks of the
-vocabulary, so the reference fits beside the bf16 weights on one chip.
+A model family (``bench/families/<model_type>.py``) computes ``hidden``,
+the final-normed hidden states, from the benchmark's weight tree with
+the helpers here; this module puts the output head over them and reads
+each served token's gap. Matrix products run at the highest precision;
+nothing here imports the serving program. The head runs over blocks of
+the vocabulary, so the reference fits beside the bf16 weights on one
+chip.
 
 ``mode="fp8"`` is the control: every operand of every matrix product is
 rounded to float8 e4m3 with a per-tensor scale, the precision one step
@@ -25,14 +21,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .model_spec import Dims
+from . import spec
 
 F32 = jnp.float32
-Q_BLOCK = 512
 V_BLOCK = 16384
 
 
-def _round(x, mode):
+def operand(x, mode):
+    """``x`` in float32, rounded first to float8 e4m3 (per-tensor scale)
+    where ``mode`` is ``fp8``."""
     if mode != "fp8":
         return x.astype(F32)
     x = x.astype(F32)
@@ -40,129 +37,31 @@ def _round(x, mode):
     return (x / s).astype(jnp.float8_e4m3fn).astype(F32) * s
 
 
-def _mm(a, b, mode):
-    return jnp.matmul(_round(a, mode), _round(b, mode),
+def mm(a, b, mode):
+    return jnp.matmul(operand(a, mode), operand(b, mode),
                       precision=jax.lax.Precision.HIGHEST)
 
 
-def _rms(x, g, eps):
+def rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
         * g.astype(F32)
 
 
-def _rope(x, pos, theta):
-    d = x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=F32) / d)
-    ang = pos[:, :, None, None].astype(F32) * inv          # (B,S,1,d/2)
-    c, s = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
-
-
-def _layer_slice(tree, i):
+def layer_slice(tree, i):
+    """Layer ``i`` of every leaf of a tree stacked over layers."""
     return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False),
                         tree)
 
 
-@partial(jax.jit, static_argnames=("dm", "mode"))
-def _attention(blocks, i, x, dm: Dims, mode):
-    """x + attention(x) for layer ``i``; returns also the FFN's input."""
-    p = _layer_slice(blocks, i)
-    a = p["attn"]
-    B, S, _ = x.shape
-    H, hk, hd = dm.heads, dm.kv_heads, dm.head_dim
-    h = _rms(x, p["attn_norm"], dm.norm_eps)
-    q, k, v = (_mm(h, a[w], mode) for w in ("wq", "wk", "wv"))
-    if dm.qkv_bias:
-        q, k, v = q + a["bq"].astype(F32), k + a["bk"].astype(F32), \
-            v + a["bv"].astype(F32)
-    pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    q = _rope(q.reshape(B, S, H, hd), pos, dm.rope_theta)
-    k = _rope(k.reshape(B, S, hk, hd), pos, dm.rope_theta)
-    v = v.reshape(B, S, hk, hd)
-    rep = H // hk
-    k = jnp.repeat(k, rep, axis=2)           # query head j reads kv head j//rep
-    v = jnp.repeat(v, rep, axis=2)
-    kpos = jnp.arange(S)
-    outs = []
-    for lo in range(0, S, Q_BLOCK):
-        qb = q[:, lo:lo + Q_BLOCK]
-        qpos = kpos[lo:lo + Q_BLOCK]
-        s = jnp.einsum("bqhd,bkhd->bhqk", _round(qb, mode), _round(k, mode),
-                       precision=jax.lax.Precision.HIGHEST) / np.sqrt(hd)
-        mask = kpos[None, :] <= qpos[:, None]
-        if dm.window:
-            mask &= kpos[None, :] > qpos[:, None] - dm.window
-        s = jnp.where(mask, s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        outs.append(jnp.einsum("bhqk,bkhd->bqhd", _round(w, mode),
-                               _round(v, mode),
-                               precision=jax.lax.Precision.HIGHEST))
-    o = jnp.concatenate(outs, 1).reshape(B, S, H * hd)
-    x = x + _mm(o, a["wo"], mode)
-    return x, _rms(x, p["ffn_norm"], dm.norm_eps)
-
-
-def _glu(h, wg, wu, wd, mode):
-    return _mm(jax.nn.silu(_mm(h, wg, mode)) * _mm(h, wu, mode), wd, mode)
-
-
-@partial(jax.jit, static_argnames=("mode",))
-def _dense_ffn(blocks, i, x, h, mode):
-    f = _layer_slice(blocks["ffn"], i)
-    return x + _glu(h, f["w_gate"], f["w_up"], f["w_down"], mode)
-
-
-@partial(jax.jit, static_argnames=("dm", "mode"))
-def _router(blocks, i, h, dm: Dims, mode):
-    """(B, S, E) gate of every expert: the renormalised top-k softmax
-    probabilities, zero for the experts a token does not use."""
-    r = jax.lax.dynamic_index_in_dim(blocks["moe"]["router"], i, 0, False)
-    probs = jax.nn.softmax(_mm(h, r, mode), -1)
-    top, idx = jax.lax.top_k(probs, dm.top_k)
-    top = top / top.sum(-1, keepdims=True)
-    return (jax.nn.one_hot(idx, dm.experts, dtype=F32) * top[..., None]
-            ).sum(-2)
-
-
-@partial(jax.jit, static_argnames=("mode",))
-def _expert(blocks, i, e, x, h, gate, mode):
-    m = blocks["moe"]
-    w = [jax.lax.dynamic_index_in_dim(
-        jax.lax.dynamic_index_in_dim(m[n], i, 0, False), e, 0, False)
-        for n in ("w_gate", "w_up", "w_down")]
-    g = jax.lax.dynamic_index_in_dim(gate, e, 2, False)[..., None]
-    return x + g * _glu(h, *w, mode)
-
-
-@partial(jax.jit, static_argnames=("dm",))
-def _embed(params, tokens, dm: Dims):
-    return jnp.take(params["embed"], tokens, axis=0).astype(F32)
-
-
-@partial(jax.jit, static_argnames=("dm", "mode"))
-def _final(params, x, dm: Dims, mode):
-    return _rms(x, params["final_norm"], dm.norm_eps)
-
-
-def hidden(params, dm: Dims, tokens, mode: str = "f32"):
-    """(B, S) tokens -> (B, S, d) final-normed hidden states."""
-    blocks = params["blocks"]
-    x = _embed(params, jnp.asarray(tokens), dm)
-    for i in range(dm.layers):
-        x, h = _attention(blocks, i, x, dm, mode)
-        if dm.experts:
-            gate = _router(blocks, i, h, dm, mode)
-            for e in range(dm.experts):
-                x = _expert(blocks, i, e, x, h, gate, mode)
-        else:
-            x = _dense_ffn(blocks, i, x, h, mode)
-    return _final(params, x, dm, mode)
+def hidden(params, dm, tokens, mode: str = "f32"):
+    """(B, S) tokens -> (B, S, d) final-normed hidden states, by
+    ``dm``'s model family."""
+    return spec.family(dm.model_type).hidden(params, dm, tokens, mode)
 
 
 @partial(jax.jit, static_argnames=("mode",))
 def _head_block(h, w, mode):
-    return _mm(h, w, mode)
+    return mm(h, w, mode)
 
 
 def logit_stats(params, h, targets):
@@ -206,7 +105,7 @@ def pack(seqs, length: int) -> np.ndarray:
     return out
 
 
-def served_gaps(params, dm: Dims, samples, length: int,
+def served_gaps(params, dm, samples, length: int,
                 control: bool = False):
     """Per served token of ``samples`` (``[(prompt, served tokens)]``):
     the gap by which its logit lies below the reference's best at its

@@ -27,8 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import accounting as acc
-from . import reference, traffic, work
-from .model_spec import Dims
+from . import reference, spec, traffic, work
 from .trace_reduce import breakdown, load as load_trace, total
 from .weights import make_weights
 
@@ -82,7 +81,7 @@ class ChunkRec:
 class Observation:
     """What a run saw; the per-layer readers take their numbers from it."""
 
-    dm: Dims
+    dm: object                       # the family's sizes
     peak: Optional[work.Peak]
     slots: int
     prefill_chunk: int
@@ -124,26 +123,13 @@ class Observation:
 
 @dataclasses.dataclass
 class Prepared:
-    dm: Dims
+    dm: object                       # the family's sizes
     cfg: object                      # the program's ModelConfig
     serving: dict
     ctx: int
     params: dict
     peak: Optional[work.Peak]
     log: dict
-
-
-def program_config(dm: Dims):
-    """The serving program's config for these sizes."""
-    from repro.configs.base import ModelConfig
-
-    return ModelConfig(
-        name=dm.name, family="moe" if dm.experts else "dense",
-        n_layers=dm.layers, d_model=dm.d, n_heads=dm.heads,
-        kv_heads=dm.kv_heads, head_dim=dm.head_dim, d_ff=dm.d_ff,
-        vocab=dm.vocab, qkv_bias=dm.qkv_bias, norm_eps=dm.norm_eps,
-        rope_theta=dm.rope_theta, n_experts=dm.experts, top_k=dm.top_k,
-        moe_capacity_factor=None, attn_window=dm.window or None)
 
 
 def _engine(prep: Prepared, metrics=None):
@@ -175,10 +161,13 @@ def warm_requests(mix: dict, vocab: int, seed: int, chunk: int):
 def prepare(config: dict, mix: dict, seed: int,
             device_kind: Optional[str], warm: bool = True) -> Prepared:
     """Weights from the seed, and (``warm``) every program compiled or
-    loaded from the cache by an engine that is then freed."""
-    dm = Dims.from_config(config)
+    loaded from the cache by an engine that is then freed. The sizes and
+    the program's config come from the configuration's model family."""
+    fam = spec.family(config["model_type"])
+    dm = fam.dims(config)
     t_a = clock()
-    prep = Prepared(dm=dm, cfg=program_config(dm), serving=config["serving"],
+    prep = Prepared(dm=dm, cfg=fam.program_config(dm),
+                    serving=config["serving"],
                     ctx=traffic.max_context(mix),
                     params=make_weights(dm, seed),
                     peak=work.peak_for(device_kind) if device_kind else None,

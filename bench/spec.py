@@ -1,7 +1,11 @@
 """Find a cell's files by the names ``BENCHMARK.json`` gives.
 
 - configuration ``<c>``: the ``file`` its entry names (sizes, source,
-  what was cut), read by ``model_spec.Dims``;
+  what was cut), in Hugging Face keys;
+- its model family: ``bench/families/<model_type>.py``, by the
+  configuration's own ``model_type`` key, with the sizes, weight
+  leaves, program config, reference and work counts of that
+  architecture (what a family provides: ``bench/families/__init__.py``);
 - traffic ``<t>``: ``bench/mixes/<t>.json``, read by the generator that
   its ``kind`` names in ``traffic.GENERATORS``;
 - the cell ``<workload>``: ``bench/cells/<workload>.json``, its fixed
@@ -9,12 +13,15 @@
 - per-layer metric ``<m>``: ``bench/layer_metrics/<m>.py`` with
   ``read(obs) -> float | None`` (dots in the name become ``_``).
 
-A later cell adds files and entries; nothing here changes.
+A later cell or architecture adds files and entries; nothing here
+changes.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import re
+import sys
 from pathlib import Path
 from typing import Callable, Dict, List
 
@@ -45,6 +52,7 @@ class Cell:
         entry = _by_name(bench["configs"], self.workload["config"],
                          "config")
         self.config = json.loads((root / entry["file"]).read_text())
+        self.family = family(self.config["model_type"], root)
         self.mix = json.loads(
             (root / "bench" / "mixes" / f"{self.workload['traffic']}.json"
              ).read_text())
@@ -60,6 +68,31 @@ class Cell:
     def _has(self, metric: dict) -> bool:
         cells = metric.get("workloads")
         return cells is None or self.name in cells
+
+
+def family(model_type: str, root: Path = ROOT):
+    """The model family of ``model_type``: ``bench/families/<model_type>.py``
+    under ``root``. A process loads each once, from the first root that
+    asks for it, and every later call returns that module (loading it
+    again would make new jitted reference functions, compiled anew)."""
+    if not re.fullmatch(r"[A-Za-z0-9_]+", model_type):
+        raise ValueError(f"model_type {model_type!r} is not a module name")
+    name = f"bench_family_{model_type}"
+    if name in sys.modules:
+        return sys.modules[name]
+    path = root / "bench" / "families" / f"{model_type}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no model family for model_type "
+                                f"{model_type!r}: {path} is not there")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[name]
+        raise
+    return mod
 
 
 def reader(metric: str, root: Path = ROOT) -> Callable:

@@ -1,6 +1,7 @@
 """A tiny cell for CPU tests: a copy of the benchmark's files in a
-temporary root, with a small configuration, mix and cell added as files
-and named in its own BENCHMARK.json."""
+temporary root, with a small configuration, mix and cell (and, where
+given, model families) added as files and named in its own
+BENCHMARK.json."""
 from __future__ import annotations
 
 import json
@@ -32,12 +33,15 @@ CELL = {"rate_per_s": 6.0, "sample": 3, "gap_statistic": "max",
 
 
 def make_root(tmp: Path, config=CONFIG, mix=MIX, cell=CELL,
-              workload="tiny.chat") -> Path:
+              workload="tiny.chat", families=None) -> Path:
     """A checkout holding the benchmark's files and one more cell, added
-    as files only."""
+    as files only; ``families`` maps a model_type to the source of its
+    ``bench/families/<model_type>.py``."""
     root = tmp / "checkout"
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    for model_type, source in (families or {}).items():
+        (root / "bench" / "families" / f"{model_type}.py").write_text(source)
     cname, tname = config["name"], workload.split(".", 1)[1]
     (root / "bench" / "configs" / f"{cname}.json").write_text(
         json.dumps(config))

@@ -19,9 +19,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from bench.model_spec import Dims  # noqa: E402
-from bench.serve_paged import program_config  # noqa: E402
-from bench.weights import leaf_shapes  # noqa: E402
+from bench import spec  # noqa: E402
 
 import json  # noqa: E402
 
@@ -37,8 +35,9 @@ def main(path: str) -> None:
     jax.config.update("jax_enable_compilation_cache", False)
 
     conf = json.loads(Path(path).read_text())
-    dm, s = Dims.from_config(conf), conf["serving"]
-    cfg = program_config(dm)
+    fam = spec.family(conf["model_type"])
+    dm, s = fam.dims(conf), conf["serving"]
+    cfg = fam.program_config(dm)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
@@ -47,7 +46,7 @@ def main(path: str) -> None:
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     params: dict = {}
-    for p, (shape, _, _) in leaf_shapes(dm).items():
+    for p, (shape, _, _) in fam.leaf_shapes(dm).items():
         node = params
         *parents, name = p.split("/")
         for k in parents:
@@ -55,8 +54,9 @@ def main(path: str) -> None:
         node[name] = sds(shape, jnp.bfloat16)
     ctx = 512 + 1536 + 512
     max_pages = -(-ctx // s["page_tokens"])
-    pages = {k: sds((dm.layers, s["n_pages"], s["page_tokens"], dm.kv_heads,
-                     dm.head_dim), jnp.bfloat16) for k in ("k", "v")}
+    pages = {k: sds((dm.layers, s["n_pages"], s["page_tokens"]) + trail,
+                    jnp.bfloat16)
+             for k, trail in kvcache.paged_cache_spec(cfg).items()}
     B = s["slots"]
     cache = {"pages": pages, "block_table": sds((B, max_pages), jnp.int32),
              "len": sds((B,), jnp.int32)}

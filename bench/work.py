@@ -6,6 +6,11 @@ uses (not the experts a lossless dispatch computes), one pass over the
 weights a step must read. So a faster implementation raises a share and
 cannot push it past 1. A multiply-add is two operations.
 
+Each model family (``bench/families/<model_type>.py``) counts its own:
+``decode_step``, ``prefill_chunk`` and, for each attention kernel's
+trace name, its per-layer count in ``kernels``. The functions of those
+names here forward to the family of the sizes they are given.
+
 Peaks come from ``peaks.json`` by the device's ``device_kind``; a kind
 that is not there is an error, never a default.
 """
@@ -16,7 +21,7 @@ import json
 from pathlib import Path
 from typing import Sequence
 
-from .model_spec import Dims
+from . import spec
 
 BF16 = 2
 PEAKS_FILE = Path(__file__).resolve().parent / "peaks.json"
@@ -67,78 +72,34 @@ def share(least: float, measured: float) -> float:
     return s
 
 
-# -- attention kernels (one layer) ------------------------------------------ #
+# -- counts by the sizes' model family -------------------------------------- #
 
-def paged_verify(dm: Dims, lengths: Sequence[int]) -> Work:
-    """One decode position per sequence against its live cache.
-    ``lengths`` counts the positions each attends to (the new one
-    included). q and out once; K and V of every live position once."""
-    H, hk, hd = dm.heads, dm.kv_heads, dm.head_dim
-    n = sum(lengths)
-    flops = 4.0 * H * hd * n                        # QK^T and PV
-    byts = BF16 * (2 * hk * hd * n + 2 * len(lengths) * H * hd)
-    return Work(flops, byts)
+def paged_verify(dm, lengths: Sequence[int]) -> Work:
+    """One decode position per sequence against its live cache, one
+    layer; ``lengths`` counts the positions each attends to."""
+    return spec.family(dm.model_type).kernels["paged_verify"](dm, lengths)
 
 
-def paged_prefill(dm: Dims, prefix: int, chunk: int) -> Work:
+def paged_prefill(dm, prefix: int, chunk: int) -> Work:
     """A chunk of ``chunk`` queries after ``prefix`` cached positions,
-    causal: query t sees prefix + t + 1 positions."""
-    H, hk, hd = dm.heads, dm.kv_heads, dm.head_dim
-    pairs = chunk * prefix + chunk * (chunk + 1) / 2
-    flops = 4.0 * H * hd * pairs
-    byts = BF16 * (2 * hk * hd * (prefix + chunk) + 2 * chunk * H * hd)
-    return Work(flops, byts)
+    causal, one layer."""
+    return spec.family(dm.model_type).kernels["paged_prefill"](
+        dm, prefix, chunk)
 
 
-# -- whole steps ---------------------------------------------------------- #
+def decode_step(dm, lengths: Sequence[int]) -> Work:
+    """One decode step of the live sequences (``lengths`` as for
+    ``paged_verify``)."""
+    return spec.family(dm.model_type).decode_step(dm, lengths)
 
-def active_experts(dm: Dims, tokens: int) -> float:
+
+def prefill_chunk(dm, prefix: int, chunk: int) -> Work:
+    """One prefill chunk of one sequence, with its full logits."""
+    return spec.family(dm.model_type).prefill_chunk(dm, prefix, chunk)
+
+
+def active_experts(dm, tokens: int) -> float:
     """Experts a step of ``tokens`` tokens must read, under seeded random
     routing: E (1 - (1 - K/E)^T)."""
     E, K = dm.experts, dm.top_k
     return E * (1.0 - (1.0 - K / E) ** tokens)
-
-
-def _dense_flops_per_token(dm: Dims) -> float:
-    """Matrix-product operations per token outside attention scores,
-    over all layers and the output head (top-k experts only)."""
-    d, H, hk, hd, f = dm.d, dm.heads, dm.kv_heads, dm.head_dim, dm.d_ff
-    proj = d * (H + 2 * hk) * hd + H * hd * d
-    ffn = 3 * d * f * (dm.top_k if dm.experts else 1)
-    router = d * dm.experts
-    return 2.0 * (dm.layers * (proj + ffn + router) + d * dm.vocab)
-
-
-def _weight_bytes(dm: Dims, tokens: int) -> float:
-    """Weights a step of ``tokens`` tokens reads once: every dense
-    weight, the experts it routes to, the output head and the tokens'
-    embedding rows."""
-    d, H, hk, hd, f = dm.d, dm.heads, dm.kv_heads, dm.head_dim, dm.d_ff
-    proj = d * (H + 2 * hk) * hd + H * hd * d
-    if dm.experts:
-        ffn = 3 * d * f * active_experts(dm, tokens) + d * dm.experts
-    else:
-        ffn = 3 * d * f
-    return BF16 * (dm.layers * (proj + ffn) + d * dm.vocab + tokens * d)
-
-
-def decode_step(dm: Dims, lengths: Sequence[int]) -> Work:
-    """One decode step of the live sequences (``lengths`` as for
-    ``paged_verify``): weights once, attention per layer, logits out."""
-    B = len(lengths)
-    attn = paged_verify(dm, lengths).scale(dm.layers)
-    kv_write = BF16 * dm.layers * 2 * dm.kv_heads * dm.head_dim * B
-    logits = BF16 * B * dm.vocab
-    return Work(_dense_flops_per_token(dm) * B,
-                _weight_bytes(dm, B) + kv_write + logits) + attn
-
-
-def prefill_chunk(dm: Dims, prefix: int, chunk: int) -> Work:
-    """One prefill chunk of one sequence, with its full logits."""
-    attn = paged_prefill(dm, prefix, chunk).scale(dm.layers)
-    kv_write = BF16 * dm.layers * 2 * dm.kv_heads * dm.head_dim * chunk
-    logits = BF16 * chunk * dm.vocab
-    return Work(_dense_flops_per_token(dm) * chunk,
-                _weight_bytes(dm, chunk) + kv_write + logits) + attn
-
-
